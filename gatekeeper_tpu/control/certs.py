@@ -161,6 +161,11 @@ class CertRotator:
             self._registrar.events.put(WatchEvent("_STOP", {}))
             for gvk in list(self._registrar.gvks):
                 self._registrar.remove_watch(gvk)
+            # a reconcile in flight may still be writing certs into
+            # cert_dir: wait it out, so the caller may remove the dir
+            t = self._reconcile_thread
+            if t is not None and t is not threading.current_thread():
+                t.join(timeout=10.0)
 
     def _loop(self) -> None:
         while not self._stop.wait(CHECK_INTERVAL):

@@ -115,7 +115,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "pinned to its own device; the leader tracks "
                         "per-slice watches, broadcasts join-relevant "
                         "columns, and composes per-shard sweeps into "
-                        "one bit-equal audit round. 1 = unsharded")
+                        "one bit-equal audit round. 1 = unsharded. "
+                        "CPU backend only: refused at start on an "
+                        "accelerator host (the parent holds every chip)")
     p.add_argument("--stream-audit", nargs="?", const=True,
                    default=False, type=_parse_bool,
                    help="with --audit-incremental: evaluate dirty rows "
@@ -219,7 +221,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "engines so the shed bound stays global. "
                         "0 = one engine per visible device. Values > 1 "
                         "imply the backplane even with "
-                        "--admission-workers 1")
+                        "--admission-workers 1. CPU backend only: "
+                        "refused at start on an accelerator host (the "
+                        "parent holds every chip; 0 means 1 there)")
     p.add_argument("--admission-shm-ring-mb", type=float, default=8.0,
                    help="shared-memory ring size (MB) per admission "
                         "frontend: review bytes ride a /dev/shm ring "
@@ -399,6 +403,31 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+def _refuse_process_per_chip(args) -> None:
+    """The N-engine admission plane and the sharded audit plane start
+    child processes that each take a device, from a parent that is
+    itself engine 0 / the audit leader and has already started JAX. On
+    an accelerator host the first process to start JAX owns every chip,
+    so those children would fail or hang. Refuse at start instead; the
+    CPU backend (tests, dry runs) splits its virtual devices fine."""
+    engines = getattr(args, "admission_engines", 1)
+    shards = getattr(args, "audit_shards", 1) or 1
+    if engines in (0, 1) and shards <= 1:
+        return
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return
+    raise SystemExit(
+        f"gatekeeper-tpu: --admission-engines={engines} / --audit-shards="
+        f"{shards} start one child process per chip, but this process "
+        f"already holds every {backend} device, so the children could "
+        "not reach theirs. Run with --admission-engines=1 "
+        "--audit-shards=1 (the audit still spans all chips through the "
+        "mesh; see ROADMAP.md S0 for the process-per-chip gap).")
+
+
 class Runtime:
     """Everything main() builds, exposed for tests and embedding."""
 
@@ -506,6 +535,7 @@ class Runtime:
         state_dir = getattr(args, "state_dir", "") or ""
         if not aot_dir and state_dir:
             aot_dir = _os.path.join(state_dir, "aot")
+        _refuse_process_per_chip(args)
         driver = TpuDriver(aot_dir=aot_dir or None)
         self.opa = Backend(driver).new_client([K8sValidationTarget()])
         self.mutation_system = None
@@ -652,12 +682,12 @@ class Runtime:
             workers = getattr(args, "admission_workers", 1) or 1
             engines = getattr(args, "admission_engines", 1)
             if engines == 0:
-                # auto: one engine per visible chip
-                try:
-                    import jax
-                    engines = max(1, len(jax.devices()))
-                except Exception:
-                    engines = 1
+                # auto: one engine per visible device where devices can
+                # be split across processes (the CPU backend); one
+                # engine on an accelerator host (_refuse_process_per_chip)
+                import jax
+                engines = (max(1, len(jax.devices()))
+                           if jax.default_backend() == "cpu" else 1)
             if engines > 1 or workers > 1:
                 from .backplane import (
                     BackplaneEngine,

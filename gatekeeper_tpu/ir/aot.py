@@ -83,7 +83,10 @@ class no_inline_compile:
 COMPILE_COUNTS = {"aot": 0, "cache": 0, "fresh": 0, "error": 0}
 _counts_lock = threading.Lock()
 
-_cache_events = {"hits": 0, "misses": 0}
+# persistent XLA cache events: lookups ("requests"), answers ("hits"),
+# and compiles written back ("misses": jax reports a miss on write,
+# so compiles under the min-compile-time floor count in neither)
+_cache_events = {"requests": 0, "hits": 0, "misses": 0}
 _monitor_registered = False
 
 
@@ -95,22 +98,27 @@ def _register_monitor() -> None:
     if _monitor_registered:
         return
     _monitor_registered = True
-    try:
-        from jax._src import monitoring
+    from jax._src import monitoring
 
-        def cb(event, **kw):
-            if event.endswith("/cache_hits"):
-                _cache_events["hits"] += 1
-            elif event.endswith("/cache_misses"):
-                _cache_events["misses"] += 1
+    def cb(event, **kw):
+        if event.endswith("/compile_requests_use_cache"):
+            _cache_events["requests"] += 1
+        elif event.endswith("/cache_hits"):
+            _cache_events["hits"] += 1
+        elif event.endswith("/cache_misses"):
+            _cache_events["misses"] += 1
 
-        monitoring.register_event_listener(cb)
-    except Exception:  # pragma: no cover - older jax without monitoring
-        pass
+    monitoring.register_event_listener(cb)
 
 
 def xla_cache_hits() -> int:
     return _cache_events["hits"]
+
+
+def xla_cache_events() -> dict:
+    """Persistent XLA cache {"requests", "hits", "misses"} events seen
+    by this process (see _cache_events)."""
+    return dict(_cache_events)
 
 
 def _report_compile(source: str, outcome: str, seconds: float) -> None:

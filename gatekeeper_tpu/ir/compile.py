@@ -34,7 +34,7 @@ Supported subset (grown corpus-first, SURVEY.md §7 P0):
     unification; `not` with locally-bound axes reduced inside the negation
 
 Anything outside raises Uncompilable(code, detail) with a code from the
-stable bounded REASON_CODES taxonomy; the driver records it, /debug/
+stable bounded REASON_CODES set; the driver records it, /debug/
 templates and gatekeeper_tpu_compile_fallback_total{reason} surface it.
 """
 
@@ -99,7 +99,7 @@ _MAX_INLINE_DEPTH = 8
 _MAX_SLOT_AXES = 2
 
 
-# Stable fallback-reason taxonomy. The metric
+# Stable fallback-reason codes. The metric
 # `gatekeeper_tpu_compile_fallback_total{reason}` labels on these codes
 # (bounded label set) and tests assert on codes, not prose — the detail
 # string is free to change, the codes are an interface.
@@ -129,7 +129,7 @@ REASON_CODES = frozenset({
     "join-data",      # data read outside the inventory generator
     "join-mixed",     # mixed inv/rev literal that is not a join equality
     "join-shape",     # violation clause not recognizable as a join
-    "internal",       # taxonomy drift guard — never raised deliberately
+    "internal",       # code-set drift guard — never raised deliberately
 })
 
 
@@ -142,7 +142,7 @@ class Uncompilable(Exception):
 
     def __init__(self, code: str, detail: str = ""):
         if code not in REASON_CODES:
-            # taxonomy drift must not crash the compile path (the caller
+            # code-set drift must not crash the compile path (the caller
             # treats Uncompilable as a routine fallback signal) — fold
             # the stray code into the detail under a stable label
             code, detail = "internal", f"{code}: {detail}" if detail else code

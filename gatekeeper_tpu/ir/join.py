@@ -767,7 +767,7 @@ class JoinCompiled:
     """Driver-facing evaluator for one join template."""
 
     def __init__(self, prog: JoinProgram, strtab, aot=None,
-                 kind: str = ""):
+                 kind: str = "", device=None, mesh=None):
         from ..rego.codegen import compile_module
         from ..rego.interp import Interpreter
         from .aot import program_fingerprint
@@ -799,6 +799,11 @@ class JoinCompiled:
         # membership at equal size
         self._dev_rev_cache: dict = {}
         self._jit = None
+        # placement: the driver's pinned device, or its mesh (review
+        # rows split over "data", inventory tables replicated); neither
+        # = the default device
+        self._device = device
+        self._mesh = mesh
 
     # ------------------------------------------------ inventory tables
 
@@ -896,17 +901,20 @@ class JoinCompiled:
 
     # ------------------------------------------------------------ fires
 
-    # below this many reviews a host dict probe beats a device dispatch
+    # admission batches below this many reviews probe the host dict: a
+    # device dispatch per small batch would also compile per batch size
     MIN_DEVICE_REVIEWS = 2048
 
     def fires(self, frz_reviews: list, inventory_tree, data_gen,
-              key_cache: Optional[dict] = None) -> np.ndarray:
-        """bool[N]: does some OTHER inventory object share a join key.
+              device: bool, key_cache: Optional[dict] = None) -> tuple:
+        """(bool[N], ran_on_device): does some OTHER inventory object
+        share a join key, probed on the device or in a host dict.
         key_cache (id(review) -> per-clause (keys, ident)), valid for one
         data generation, makes steady-state audits skip re-extraction."""
         tabs = self.inv_tables(inventory_tree, data_gen)
         n = len(frz_reviews)
         out = np.zeros(n, dtype=bool)
+        on_device = False
         for ci, (u, cnt, sik, host) in enumerate(tabs):
             if not len(u):
                 continue
@@ -928,10 +936,11 @@ class JoinCompiled:
                 hmax = max(hmax, len(ks))
             if hmax == 0:
                 continue
-            if n >= self.MIN_DEVICE_REVIEWS:
+            if device:
                 out |= self._fires_device(
                     ci, u, cnt, sik, keys, iks, hmax,
                     (data_gen, id(inventory_tree)))
+                on_device = True
             else:
                 for r in range(n):
                     if out[r]:
@@ -946,24 +955,37 @@ class JoinCompiled:
                                            in zip(hit[1], iks[r]))):
                             out[r] = True
                             break
-        return out
+        return out, on_device
 
     def _fires_device(self, ci, u, cnt, sik, keys, iks, hmax,
                       inv_key) -> np.ndarray:
         """Device membership: pad keys to [N, H], searchsorted into the
         padded unique-key table, apply count/identity rules. One jit per
-        (H bucket, K bucket) shape. Inventory tensors are cached per
-        (clause, data generation, tree identity); review tensors are
-        rebuilt on host every call and their device copies reused only
-        when the BYTES match — steady-state audits (same candidate list)
-        skip the H2D upload, while a changed candidate set of equal size
-        never sees stale keys."""
+        (N bucket, H bucket, K bucket) shape. Inventory tensors are
+        cached per (clause, data generation, tree identity); review
+        tensors are rebuilt on host every call and their device copies
+        reused only when the BYTES match — steady-state audits (same
+        candidate list) skip the H2D upload, while a changed candidate
+        set of equal size never sees stale keys."""
         import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from .features import _bucket
 
         # int32 throughout: jax runs with x64 disabled, which would
         # silently truncate int64 inputs (interned sids always fit)
         n = len(keys)
         G = sik.shape[0]
+        # rows padded to their power-of-two bucket: a changed inventory
+        # size re-hits the compiled program (pad rows hold KEY_PAD keys
+        # and never fire), and the bucket divides the mesh's data axis
+        n_pad = _bucket(n)
+        if self._mesh is not None:
+            n_pad = max(n_pad, self._mesh.shape["data"])
+            inv_put = NamedSharding(self._mesh, P())
+            rev_put = NamedSharding(self._mesh, P("data", None))
+        else:
+            inv_put = rev_put = self._device
         h = 1
         while h < hmax:
             h *= 2
@@ -981,23 +1003,26 @@ class JoinCompiled:
             cnt_p[:len(u)] = cnt
             sik_p = np.full((G, kb), IK_MULTI, dtype=np.int32)
             sik_p[:, :len(u)] = sik
-            inv_args = tuple(jax.device_put(a) for a in (u_p, cnt_p, sik_p))
+            inv_args = tuple(jax.device_put(a, inv_put)
+                             for a in (u_p, cnt_p, sik_p))
             self._dev_inv_cache[ci] = (inv_key, (kb, G), inv_args)
 
-        karr = np.full((n, h), KEY_PAD, dtype=np.int32)
+        karr = np.full((n_pad, h), KEY_PAD, dtype=np.int32)
         for r, ks in enumerate(keys):
             karr[r, :len(ks)] = ks
-        iks32 = iks.astype(np.int32)
+        iks32 = np.full((n_pad, G), IK_REV_MISSING, dtype=np.int32)
+        iks32[:n] = iks
         kb_bytes, ik_bytes = karr.tobytes(), iks32.tobytes()
         rev = self._dev_rev_cache.get(ci)
         if rev is not None and rev[0] == kb_bytes and rev[1] == ik_bytes:
             rev_args = rev[2]
         else:
-            rev_args = (jax.device_put(karr), jax.device_put(iks32))
+            rev_args = (jax.device_put(karr, rev_put),
+                        jax.device_put(iks32, rev_put))
             self._dev_rev_cache[ci] = (kb_bytes, ik_bytes, rev_args)
         args = inv_args + rev_args
 
-        return np.asarray(self._jit_wrapper()(*args))
+        return np.asarray(self._jit_wrapper()(*args))[:n]
 
     def _jit_wrapper(self):
         if self._jit is None:

@@ -2,8 +2,8 @@
 
 The audit's device sweep answers "which (object, constraint) pairs fire"
 in ~0.3s; turning those pairs into violation MESSAGES was ~3x slower
-than the sweep itself (BENCH_r04/r05: `materialize_s` ~ 3x
-`sweep_wall_s`) because every firing pair re-ran the template's codegen
+than the sweep itself (the bench rounds before PR 11: `materialize_s`
+~ 3x `sweep_wall_s`) because every firing pair re-ran the template's codegen
 evaluator in Python just to rebuild a string the clause head already
 determines. This module removes that Python-per-pair work for the
 common head shape:

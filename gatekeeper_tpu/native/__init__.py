@@ -8,6 +8,7 @@ stays importable anywhere. Disable with GATEKEEPER_TPU_NATIVE=0."""
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,11 +23,16 @@ _tried = False
 
 
 def _build() -> Optional[str]:
+    """Path of the extension built from THIS flatten.c, building it if
+    needed. The artifact is named by a hash of the source, so a stale
+    or foreign .so (copied along with a working tree, left by an older
+    checkout) is never loaded, whatever its mtime."""
     src = os.path.join(_DIR, "flatten.c")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    out = os.path.join(_DIR, "_flatten" + suffix)
-    if os.path.exists(out) and \
-            os.path.getmtime(out) >= os.path.getmtime(src):
+    out = os.path.join(_DIR, f"_flatten-{digest}{suffix}")
+    if os.path.exists(out):
         return out
     cc = os.environ.get("CC", "cc")
     include = sysconfig.get_path("include")

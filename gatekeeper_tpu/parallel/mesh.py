@@ -26,25 +26,11 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# shard_map graduated from jax.experimental in newer releases, and its
-# replication-check kwarg was renamed check_rep -> check_vma; this wrap
-# is the ONE place that absorbs both differences (evaljax's mesh sweep
-# and collectives' audit step both route through it)
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def shard_map_wrap(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking disabled, under whichever
-    kwarg spelling this jax version takes."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """shard_map with replication checking disabled (evaljax's mesh
+    sweep and collectives' audit step both route through it)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(devices=None, data: Optional[int] = None,

@@ -112,6 +112,162 @@ def synth_constraints(c: int, seed: int = 1):
     return out
 
 
+def synth_mixed_objects(n: int, seed: int = 0) -> list[dict]:
+    """Pods/Deployments/Ingresses/Services with fields the general
+    library examines (images, limits/requests, labels, tls/hosts,
+    selectors). ~2% violate something."""
+    rng = random.Random(seed)
+    repos = ["registry.corp.example/", "gcr.io/corp/"]
+    out = []
+    for i in range(n):
+        kind = ("Pod", "Pod", "Pod", "Deployment", "Ingress",
+                "Service")[i % 6]
+        name = f"{kind.lower()}-{i}"
+        labels = {"owner": "team.corp.example", "app": f"app{i % 50}"}
+        bad = rng.random() < 0.02
+        if kind == "Pod":
+            image = (rng.choice(repos) + f"svc{i % 20}:v1"
+                     if not bad else f"docker.io/evil{i}:latest")
+            cpu = "900m" if not bad else "4"
+            out.append({
+                "apiVersion": "v1", "kind": "Pod",
+                "metadata": {"name": name, "namespace": f"ns{i % 20}",
+                             "labels": labels},
+                "spec": {"containers": [{
+                    "name": "main", "image": image,
+                    "resources": {
+                        "limits": {"cpu": cpu, "memory": "512Mi"},
+                        "requests": {"cpu": "250m", "memory": "256Mi"}},
+                }]},
+            })
+        elif kind == "Deployment":
+            out.append({
+                "apiVersion": "apps/v1", "kind": "Deployment",
+                "metadata": {"name": name, "namespace": f"ns{i % 20}",
+                             "labels": labels},
+                "spec": {"replicas": 2,
+                         "selector": {"matchLabels": {"app": f"app{i}"}}},
+            })
+        elif kind == "Ingress":
+            spec = {"rules": [{"host": f"h{i}.corp.example"}]}
+            meta = {"name": name, "namespace": f"ns{i % 20}",
+                    "labels": labels}
+            if not bad:
+                spec["tls"] = [{"hosts": [f"h{i}.corp.example"]}]
+                meta["annotations"] = {
+                    "kubernetes.io/ingress.allow-http": "false"}
+            out.append({"apiVersion": "networking.k8s.io/v1beta1",
+                        "kind": "Ingress", "metadata": meta, "spec": spec})
+        else:
+            out.append({
+                "apiVersion": "v1", "kind": "Service",
+                "metadata": {"name": name, "namespace": f"ns{i % 20}",
+                             "labels": labels},
+                "spec": {"selector": {"app": f"app{i}"},
+                         "ports": [{"port": 80}]},
+            })
+    return out
+
+
+GENERAL_CONSTRAINTS = [
+    ("K8sAllowedRepos", "repos-allowed",
+     {"repos": ["registry.corp.example/", "gcr.io/corp/"]}),
+    ("K8sContainerLimits", "limits-capped", {"cpu": "2", "memory": "1Gi"}),
+    ("K8sContainerRatios", "ratio-capped", {"ratio": "4"}),
+    ("K8sHttpsOnly", "https-only", None),
+    ("K8sRequiredLabels", "must-own",
+     {"labels": [{"key": "owner",
+                  "allowedRegex": "^[a-z]+.corp.example$"}]}),
+    ("K8sUniqueIngressHost", "unique-hosts", None),
+    ("K8sUniqueServiceSelector", "unique-selectors", None),
+]
+
+
+def synth_pods_psp(n: int, seed: int = 0) -> list[dict]:
+    """Pod specs exercising the PSP library's fields; ~3% violate."""
+    rng = random.Random(seed)
+    out = []
+    for i in range(n):
+        bad = rng.random() < 0.03
+        ctx = {"allowPrivilegeEscalation": False,
+               "readOnlyRootFilesystem": True,
+               "runAsUser": 1000 + (i % 1000),
+               "capabilities": {"drop": ["ALL"]}}
+        if bad:
+            kind_of_bad = rng.randrange(5)
+            if kind_of_bad == 0:
+                ctx["privileged"] = True
+            elif kind_of_bad == 1:
+                ctx["runAsUser"] = 0
+            elif kind_of_bad == 2:
+                ctx["capabilities"] = {"add": ["SYS_ADMIN"], "drop": []}
+            elif kind_of_bad == 3:
+                ctx.pop("readOnlyRootFilesystem")
+            else:
+                ctx["allowPrivilegeEscalation"] = True
+        pod = {
+            "apiVersion": "v1", "kind": "Pod",
+            "metadata": {
+                "name": f"pod-{i}", "namespace": f"ns{i % 40}",
+                "annotations": {
+                    "seccomp.security.alpha.kubernetes.io/pod":
+                        "runtime/default",
+                    "container.apparmor.security.beta.kubernetes.io/main":
+                        "runtime/default",
+                },
+            },
+            "spec": {
+                "securityContext": {"fsGroup": 2000,
+                                    "sysctls": ([{"name": "net.ipv4.ip_local_port_range", "value": "1024 65535"}]
+                                                if i % 7 else [{"name": "kernel.msgmax", "value": "1"}])},
+                "containers": [{
+                    "name": "main",
+                    "image": f"registry.corp.example/app{i % 100}:v1",
+                    "securityContext": ctx,
+                    "ports": ([{"hostPort": 8080 + (i % 100)}]
+                              if i % 11 == 0 else []),
+                }],
+                "volumes": [{"name": "cfg", "configMap": {"name": "c"}}] +
+                           ([{"name": "h", "hostPath":
+                              {"path": f"/var/log/app{i}"}}]
+                            if i % 13 == 0 else []),
+            },
+        }
+        out.append(pod)
+    return out
+
+
+PSP_CONSTRAINTS = [
+    ("K8sPSPAllowPrivilegeEscalationContainer", "no-escalation", None),
+    ("K8sPSPAppArmor", "apparmor-default",
+     {"allowedProfiles": ["runtime/default"]}),
+    ("K8sPSPCapabilities", "caps",
+     {"allowedCapabilities": ["NET_BIND_SERVICE"],
+      "requiredDropCapabilities": ["ALL"]}),
+    ("K8sPSPFlexVolumes", "flex", {"allowedFlexVolumes": []}),
+    ("K8sPSPForbiddenSysctls", "sysctls",
+     {"forbiddenSysctls": ["kernel.*", "vm.swappiness"]}),
+    ("K8sPSPFSGroup", "fsgroup",
+     {"rule": "MustRunAs", "ranges": [{"min": 1000, "max": 65535}]}),
+    ("K8sPSPHostFilesystem", "hostfs",
+     {"allowedHostPaths": [{"pathPrefix": "/var/log", "readOnly": True}]}),
+    ("K8sPSPHostNamespace", "no-host-ns", None),
+    ("K8sPSPHostNetworkingPorts", "host-ports",
+     {"hostNetwork": False, "min": 8000, "max": 9000}),
+    ("K8sPSPPrivilegedContainer", "no-privileged", None),
+    ("K8sPSPProcMount", "procmount", {"procMount": "Default"}),
+    ("K8sPSPReadOnlyRootFilesystem", "ro-root", None),
+    ("K8sPSPSeccomp", "seccomp",
+     {"allowedProfiles": ["runtime/default", "docker/default"]}),
+    ("K8sPSPSELinux", "selinux",
+     {"allowedSELinuxOptions": {"level": "s0:c123,c456"}}),
+    ("K8sPSPAllowedUsers", "users",
+     {"runAsUser": {"rule": "MustRunAsNonRoot"}}),
+    ("K8sPSPVolumeTypes", "volumes",
+     {"volumes": ["configMap", "secret", "emptyDir", "hostPath"]}),
+]
+
+
 def build_eval_setup(n_objects: int, n_constraints: int, seed: int = 0,
                      n_bucket: int | None = None,
                      violate_frac: float = 0.01):

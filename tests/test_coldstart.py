@@ -280,8 +280,11 @@ def test_aot_warm_boot_adopts_sweep_sigs(tmp_path, fresh_xla_cache):
     assert not d2._eval_counts.get(("K8sRequiredLabels", "interp"))
 
 
+# the default chunk makes the 2048-row sweep one lazy chunk (run under
+# the guard by _dispatch_guarded); 256 makes it a slab dispatch
+@pytest.mark.parametrize("sweep_chunk", [8192, 256])
 def test_adopted_sig_without_executable_serves_host_not_inline_compile(
-        tmp_path, fresh_xla_cache):
+        tmp_path, fresh_xla_cache, sweep_chunk):
     """A warm-boot-adopted sweep signature whose backing executable is
     gone (store GC'd, save refused on the previous boot) must NOT stall
     the serving path on an inline XLA compile: the sig is un-adopted,
@@ -289,6 +292,7 @@ def test_adopted_sig_without_executable_serves_host_not_inline_compile(
     in the background."""
     aot_dir = str(tmp_path / "aot")
     d1 = _single_device_driver(aot_dir=aot_dir)
+    d1.sweep_chunk = sweep_chunk
     c1 = _labels_client(d1, 2048, 5)
     got1 = _key(c1.audit().results())
     assert d1.aot.programs_count() >= 1
@@ -303,6 +307,7 @@ def test_adopted_sig_without_executable_serves_host_not_inline_compile(
     os.environ["GATEKEEPER_TPU_ASYNC_COMPILE"] = "1"
     try:
         d2 = _single_device_driver(aot_dir=aot_dir)
+        d2.sweep_chunk = sweep_chunk
         # pin the host model fast so the block-when-cheaper rule picks
         # the host fallback (the guard's outcome is then observable as
         # an interp eval instead of a waited-out background warm)
@@ -446,3 +451,23 @@ def test_warm_cache_cli_prepacks_from_snapshots(tmp_path, capsys):
     assert summary["restored"]["library"] and summary["objects"] == 2048
     assert summary["programs_stored"] >= 1
     assert os.path.isdir(os.path.join(state, "aot"))
+
+
+@pytest.mark.parametrize("ncpu,limits,slots", [
+    (224, (1.0, 2 << 30), 1),    # the chart's pod on a large host
+    (224, (None, None), 4),      # no limits: capped
+    (13, (None, 40 << 30), 4),   # the one-chip machine
+    (4, (None, None), 2),
+    (224, (8.0, 4 << 30), 2),    # memory binds before the CPU quota
+])
+def test_warm_compile_slots_follow_the_process_limits(monkeypatch, ncpu,
+                                                      limits, slots):
+    """Background compiles are sized from the CPUs and memory this
+    process may use (affinity, cgroup quota and limit), not from the
+    machine's core count."""
+    from gatekeeper_tpu.ir import driver as drv_mod
+
+    monkeypatch.setattr(drv_mod.os, "sched_getaffinity",
+                        lambda _pid: set(range(ncpu)))
+    monkeypatch.setattr(drv_mod, "_cgroup_limits", lambda: limits)
+    assert drv_mod.warm_compile_slots() == slots

@@ -1,4 +1,4 @@
-"""Full-library device compilation: coverage, bit-equality, taxonomy.
+"""Full-library device compilation: coverage, bit-equality, reason codes.
 
 PR 10's contract: every shipped kind — both libraries, including the
 cross-object join templates — evaluates through a device program, with
@@ -11,7 +11,7 @@ holds that with three instruments:
     synthetic inventory — verdicts AND messages must equal the
     interpreter driver's for every kind, with the eval-path counters
     proving the device/join paths actually served;
-  * taxonomy: an interpreter-bound kind records a STABLE Uncompilable
+  * reason codes: an interpreter-bound kind records a STABLE Uncompilable
     reason code (bounded metric label set, asserted on codes not prose)
     through driver state, /debug/templates, and the
     gatekeeper_tpu_compile_fallback_total metric.
@@ -340,10 +340,10 @@ violation[{"msg": msg}] {
     assert outs["RegoDriver"][3], "true conflict must fire"
 
 
-# -------------------------------------------------------------- taxonomy
+# -------------------------------------------------------------- reason codes
 
 
-def test_fallback_reason_taxonomy():
+def test_fallback_reason_codes():
     """An interpreter-bound kind records a STABLE reason code — in
     fallback_reasons(), /debug/templates, and the bounded-label
     gatekeeper_tpu_compile_fallback_total metric."""
@@ -435,7 +435,7 @@ violation[{"msg": msg}] {
 
 
 def test_unknown_reason_code_folds_to_internal():
-    """Taxonomy drift (a raise site with a stray code) must not widen
+    """Reason-code drift (a raise site with a stray code) must not widen
     the metric label set — it folds into the stable 'internal' code."""
     e = Uncompilable("no-such-code", "something odd")
     assert e.code == "internal"

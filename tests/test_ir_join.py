@@ -121,14 +121,11 @@ def test_join_device_path_matches_host_path():
     reviews = drv._inventory_reviews("admission.k8s.gatekeeper.sh")
     frz = [freeze(r) for r in reviews]
     inv = drv._inventory_tree("admission.k8s.gatekeeper.sh")
-    host = jc.fires(frz, inv, drv._data_gen)
-    saved = jc.MIN_DEVICE_REVIEWS
-    try:
-        jc.MIN_DEVICE_REVIEWS = 1  # force the device path
-        jc._jit = None
-        dev = jc.fires(frz, inv, drv._data_gen)
-    finally:
-        jc.MIN_DEVICE_REVIEWS = saved
+    host, on_device = jc.fires(frz, inv, drv._data_gen, device=False)
+    assert not on_device
+    jc._jit = None
+    dev, on_device = jc.fires(frz, inv, drv._data_gen, device=True)
+    assert on_device
     assert (np.asarray(host) == np.asarray(dev)).all()
     assert host.any(), "non-vacuous: some host collisions must fire"
 
@@ -162,15 +159,10 @@ def test_join_device_cache_not_keyed_by_shape_alone():
 
     batch_a = [rv(f"a{i}", "nsA", [f"free{i}.com"]) for i in range(4)]
     batch_b = [rv(f"b{i}", "nsB", ["dup.com"]) for i in range(4)]
-    saved = jc.MIN_DEVICE_REVIEWS
-    try:
-        jc.MIN_DEVICE_REVIEWS = 1  # force the device path
-        fa = jc.fires(batch_a, inv, drv._data_gen)
-        fb = jc.fires(batch_b, inv, drv._data_gen)
-        # and back again, to also catch reuse in the other direction
-        fa2 = jc.fires(batch_a, inv, drv._data_gen)
-    finally:
-        jc.MIN_DEVICE_REVIEWS = saved
+    fa, _ = jc.fires(batch_a, inv, drv._data_gen, device=True)
+    fb, _ = jc.fires(batch_b, inv, drv._data_gen, device=True)
+    # and back again, to also catch reuse in the other direction
+    fa2, _ = jc.fires(batch_a, inv, drv._data_gen, device=True)
     assert not np.asarray(fa).any(), "batch A has no collisions"
     assert np.asarray(fb).all(), "batch B must all fire"
     assert not np.asarray(fa2).any(), "stale device tensors reused"

@@ -1098,7 +1098,7 @@ def _write_round(d, n, doc):
 
 
 def test_bench_trend_check_passes_on_committed_history():
-    """The acceptance gate: the committed BENCH_r01-r05 trajectory must
+    """The acceptance gate: the committed BENCH_r05-r07 trajectory must
     pass --check (scale changes between rounds restart series via the
     unit string; they are not regressions)."""
     import io
@@ -1113,7 +1113,7 @@ def test_bench_trend_check_passes_on_committed_history():
     assert rc == 0, buf.getvalue()
     report = buf.getvalue()
     assert "# Benchmark trend" in report
-    assert "r01" in report and "r05" in report
+    assert "r05" in report and "r07" in report
 
 
 def test_bench_trend_fails_on_synthetic_regression(tmp_path):
