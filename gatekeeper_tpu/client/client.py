@@ -21,6 +21,7 @@ import json
 import threading
 from typing import Any, Iterable, Optional, Union
 
+from ..utils import profiling
 from .crd import CRDError, create_crd, create_schema, validate_cr, validate_crd
 from .drivers import Driver, hook_audit_path, hook_violation_path
 from .rewriter import RewriteError, rewrite_template_modules
@@ -451,19 +452,21 @@ class Client:
             return out
 
     def audit(self, tracing: bool = False) -> Responses:
-        with self._lock:
+        with profiling.span("audit"), self._lock:
             return self._audit_locked(tracing)
 
     def _audit_locked(self, tracing: bool) -> Responses:
         responses = Responses()
         errs = ErrorMap()
+        timers = profiling.timers()
         for name, handler in self.targets.items():
             try:
                 resp = self.driver.query(hook_audit_path(name), None,
                                          tracing=tracing)
                 memo: dict = {}
-                for r in resp.results:
-                    handler.handle_violation(r, memo)
+                with timers.phase("handle_violations"):
+                    for r in resp.results:
+                        handler.handle_violation(r, memo)
             except Exception as e:
                 errs[name] = e
                 continue

@@ -1255,8 +1255,7 @@ class AuditManager:
         # histograms exist regardless of the admission sample rate.
         # The driver's internals accumulate into the process-global
         # PhaseTimers; the snapshot diff below turns this sweep's
-        # encode / device_sweep / materialize / interp_eval /
-        # delta_serve time into trace phases.
+        # phase times (control/stages.py) into trace phases.
         tr = gtrace.TRACER.start(gtrace.AUDIT, force=True)
         try:
             # evaluation is serialized with the streaming flush (both
@@ -1357,13 +1356,12 @@ class AuditManager:
         if writer is not None:
             streamed_kinds = writer.finish()
             stream_write_s = writer.wall_s
-        # phase attribution, double-count-free: when the driver
-        # instrumented its internals (encode / device_sweep /
-        # materialize / interp_eval / delta_serve — all inside the
-        # evaluation wall), the trace records THOSE plus the
-        # uncovered remainder as evaluate_other, so stages sum to the
-        # sweep. An uninstrumented driver records one aggregate
-        # evaluate span instead. status_write accrues on the streaming
+        # phase attribution, double-count-free: when the client and
+        # driver timed phases (disjoint, all inside the evaluation
+        # wall), the trace records THOSE plus the uncovered remainder
+        # as evaluate_other, so stages sum to the sweep. A sweep with
+        # no phase records one aggregate evaluate span instead.
+        # status_write accrues on the streaming
         # writer's OWN thread (overlapping the sweep) — it is reported
         # as its own phase, never subtracted from the eval wall.
         phases = profiling.PhaseTimers.diff(phases0, timers.snapshot())

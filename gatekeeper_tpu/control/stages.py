@@ -52,13 +52,28 @@ STAGES: dict[str, tuple[str, str]] = {
     "list_delta_apply": (
         "audit", "inventory list / watch-delta application ahead of "
         "the sweep"),
+    "match": (
+        "audit", "match mask and candidate reviews of one kind"),
     "encode": (
         "audit", "review encoding into the dense feature tensors"),
+    "join": (
+        "audit", "join kinds: review key freeze and the device join"),
+    "join_materialize": (
+        "audit", "join kinds: message rendering of the firing pairs "
+        "(the template's evaluator, reading the inventory)"),
+    "delta_store": (
+        "audit", "seeding the audit results delta cache from a full "
+        "sweep"),
+    "handle_violations": (
+        "audit", "client: resource extraction of every audit result"),
     "delta_serve": (
         "audit", "incremental encoded-row cache serve (dirty-row "
         "re-encode)"),
+    "dispatch": (
+        "audit", "asynchronous launch of one kind's device sweep"),
     "device_sweep": (
-        "audit", "XLA sweep dispatch + device wait"),
+        "audit", "device wait (and deferred launches) for each slab of "
+        "a kind's sweep"),
     "materialize": (
         "audit", "violation message materialization from firing "
         "(row, constraint) pairs"),

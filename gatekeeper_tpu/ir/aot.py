@@ -415,9 +415,13 @@ class AotStore:
 
     # ------------------------------------------------------ executables
 
+    # bumped when what a stored executable holds changes for the same
+    # key (2: modules named jit_<tag>), so older entries are not served
+    KEY_VERSION = 2
+
     def entry_key(self, fingerprint: str, tag: str, static: tuple,
                   asig: tuple) -> str:
-        h = hashlib.sha256(repr((fingerprint, tag, static,
+        h = hashlib.sha256(repr((self.KEY_VERSION, fingerprint, tag, static,
                                  asig)).encode()).hexdigest()
         return h[:40]
 
@@ -557,9 +561,18 @@ class AotJit:
     def __init__(self, fn, store: Optional[AotStore] = None,
                  fingerprint: str = "", tag: str = "",
                  static: tuple = (), kind: str = ""):
+        import functools
+
         import jax
 
-        self._jit = jax.jit(fn)
+        # the XLA module is named after the tag (jit_<tag>), so a
+        # profile names the program each device operation belongs to
+        @functools.wraps(fn)
+        def named(*args, **kw):
+            return fn(*args, **kw)
+
+        named.__name__ = named.__qualname__ = tag or fn.__name__
+        self._jit = jax.jit(named)
         self._store = store
         self._fingerprint = fingerprint
         self._tag = tag

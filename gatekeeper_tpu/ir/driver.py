@@ -1714,16 +1714,18 @@ class TpuDriver(RegoDriver):
                                                sig_cache)
             emit(kind)
         if trace is None:
-            for kind in sorted(by_kind):
-                # seed/refresh the delta cache from this full sweep —
-                # only for kinds that stayed compiled (a mid-sweep
-                # demotion means the interpreter path, whose templates
-                # may read inventory) and are review-pure
-                if kind not in delta_served and \
-                        self._compiled.get(kind) is not None and \
-                        not self._template_reads_data(kind):
-                    self._audit_delta_store(target, kind,
-                                            by_res.get(kind, []), reviews)
+            with profiling.timers().phase("delta_store"):
+                for kind in sorted(by_kind):
+                    # seed/refresh the delta cache from this full sweep —
+                    # only for kinds that stayed compiled (a mid-sweep
+                    # demotion means the interpreter path, whose
+                    # templates may read inventory) and are review-pure
+                    if kind not in delta_served and \
+                            self._compiled.get(kind) is not None and \
+                            not self._template_reads_data(kind):
+                        self._audit_delta_store(target, kind,
+                                                by_res.get(kind, []),
+                                                reviews)
         for kind in sorted(by_kind):
             results.extend(by_res.get(kind, []))
         self.last_audit_path = (
@@ -1912,14 +1914,16 @@ class TpuDriver(RegoDriver):
         candidate set, so a second call is a cache hit). -> None when no
         review is a candidate, else (mask, cand, cand_reviews, use_mesh,
         (feats, enc, table, derived))."""
-        mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
-                                sig_cache)
-        cand = np.flatnonzero(mask.any(axis=1))
-        if cand.size == 0:
-            return None
-        cand_reviews = [reviews[int(i)] for i in cand]
+        timers = profiling.timers()
+        with timers.phase("match", kind=kind):
+            mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
+                                    sig_cache)
+            cand = np.flatnonzero(mask.any(axis=1))
+            if cand.size == 0:
+                return None
+            cand_reviews = [reviews[int(i)] for i in cand]
         use_mesh = self._mesh_shardable(len(cand_reviews))
-        with profiling.timers().phase("encode"):
+        with timers.phase("encode", kind=kind):
             trees = self._prepare_eval(
                 ct, kind, cand_reviews, cons,
                 (self._data_gen, hash(cand.tobytes())), cand=cand,
@@ -1949,45 +1953,47 @@ class TpuDriver(RegoDriver):
             feats, enc, table, derived = trees
             c_dev = _param_c(enc)
             n_cons = len(cons)
-            sig = self._sweep_sig(kind, feats, enc, table, derived,
-                                  len(cand_reviews), use_mesh)
-            def warm_run():
-                h = self._dispatch_handle(ct, feats, enc, table,
-                                          derived, len(cand_reviews),
-                                          use_mesh, n_cons=n_cons)
-                for _ in h.pairs():
-                    pass
-            if self.async_warm:
-                # host fallback only when it is actually cheaper than
-                # waiting out the compile: at audit scale (e.g. 50M
-                # masked pairs) minutes of interpretation would be far
-                # worse than blocking ~10-90s once
-                if not self._warm_gate(sig, kind, warm_run,
-                                       int(mask.sum()),
-                                       fingerprint=ct.fingerprint,
-                                       what=f"mesh={use_mesh}"):
-                    return None  # host path serves this audit
+            with profiling.timers().phase("dispatch", kind=kind):
+                sig = self._sweep_sig(kind, feats, enc, table, derived,
+                                      len(cand_reviews), use_mesh)
+
+                def warm_run():
+                    h = self._dispatch_handle(ct, feats, enc, table,
+                                              derived, len(cand_reviews),
+                                              use_mesh, n_cons=n_cons)
+                    for _ in h.pairs():
+                        pass
+                if self.async_warm:
+                    # host fallback only when it is actually cheaper than
+                    # waiting out the compile: at audit scale (e.g. 50M
+                    # masked pairs) minutes of interpretation would be
+                    # far worse than blocking ~10-90s once
+                    if not self._warm_gate(sig, kind, warm_run,
+                                           int(mask.sum()),
+                                           fingerprint=ct.fingerprint,
+                                           what=f"mesh={use_mesh}"):
+                        return None  # host path serves this audit
+                handle = self._dispatch_guarded(sig, ct, feats, enc, table,
+                                                derived, len(cand_reviews),
+                                                use_mesh, n_cons)
+                if handle is None:
+                    # the adopted signature didn't hold: re-gate it as
+                    # cold (background warm + host fallback, or block-
+                    # when-cheaper per the cost model)
+                    if self.async_warm and not self._warm_gate(
+                            sig, kind, warm_run, int(mask.sum()),
+                            fingerprint=ct.fingerprint,
+                            what=f"mesh={use_mesh}"):
+                        return None
+                    handle = self._dispatch_handle(
+                        ct, feats, enc, table, derived, len(cand_reviews),
+                        use_mesh, n_cons=n_cons)
+                # the program(s) for this shape are compiled/deserialized
+                # by now (dispatch traces them): remember the signature so
+                # a restarted process marks it warm before its first sweep
+                self.aot.record_sig(ct.fingerprint, sig)
             import time as _time
 
-            handle = self._dispatch_guarded(sig, ct, feats, enc, table,
-                                            derived, len(cand_reviews),
-                                            use_mesh, n_cons)
-            if handle is None:
-                # the adopted signature didn't hold: re-gate it as cold
-                # (background warm + host fallback, or block-when-
-                # cheaper per the cost model)
-                if self.async_warm and not self._warm_gate(
-                        sig, kind, warm_run, int(mask.sum()),
-                        fingerprint=ct.fingerprint,
-                        what=f"mesh={use_mesh}"):
-                    return None
-                handle = self._dispatch_handle(
-                    ct, feats, enc, table, derived, len(cand_reviews),
-                    use_mesh, n_cons=n_cons)
-            # the program(s) for this shape are compiled/deserialized by
-            # now (dispatch traces them): remember the signature so a
-            # restarted process marks it warm before its first sweep
-            self.aot.record_sig(ct.fingerprint, sig)
             if use_mesh:
                 self._audit_used_mesh = True
             self._audit_on_device = True
@@ -2153,13 +2159,10 @@ class TpuDriver(RegoDriver):
         _tag, mask, cand, cand_reviews, handle, c_dev, t_dispatch = st
         import time as _time
 
+        timers = profiling.timers()
         out: list[Result] = []
         first_sync = True
-        # two stopwatches through one slab loop: time blocked on the
-        # device (generator next) vs host materialization — the audit
-        # trace's device_sweep / materialize phases (a context manager
-        # per slab would mis-nest across the interleaving)
-        t_dev = t_mat = 0.0
+        t_busy0 = _time.monotonic()
         # mesh handles label blocks with their data-shard index: the
         # per-shard materialize histograms ride that, and — since the
         # SLAB loop's blocks are not globally row-major — results are
@@ -2171,13 +2174,10 @@ class TpuDriver(RegoDriver):
             it = iter(labeled()) if labeled is not None \
                 else iter(handle.pairs())
             while True:
-                t0 = _time.monotonic()
-                try:
-                    item = next(it)
-                except StopIteration:
-                    t_dev += _time.monotonic() - t0
+                with timers.phase("device_sweep", kind=kind):
+                    item = next(it, None)
+                if item is None:
                     break
-                t_dev += _time.monotonic() - t0
                 shard = None
                 if labeled is not None:
                     shard, rows, cols = item
@@ -2196,14 +2196,14 @@ class TpuDriver(RegoDriver):
                                       _time.monotonic() - t_dispatch)
                     first_sync = False
                 t0 = _time.monotonic()
-                rows, cols = _expand_parameterless(rows, cols, c_dev,
-                                                   len(cons))
-                keep = mask[cand[rows], cols]
-                res = self.materialize_pairs(
-                    target, cons, cand_reviews, rows[keep], cols[keep],
-                    inventory, cand=cand)
+                with timers.phase("materialize", kind=kind):
+                    rows, cols = _expand_parameterless(rows, cols, c_dev,
+                                                       len(cons))
+                    keep = mask[cand[rows], cols]
+                    res = self.materialize_pairs(
+                        target, cons, cand_reviews, rows[keep], cols[keep],
+                        inventory, cand=cand)
                 dt = _time.monotonic() - t0
-                t_mat += dt
                 if shard is None:
                     out.extend(res)
                 else:
@@ -2223,12 +2223,7 @@ class TpuDriver(RegoDriver):
             return self._audit_interp(target, kind, cons, reviews,
                                       lookup_ns, inventory, None, sig_cache)
         finally:
-            timers = profiling.timers()
-            if t_dev > 0:
-                timers.add("device_sweep", t_dev)
-            if t_mat > 0:
-                timers.add("materialize", t_mat)
-            self.note_busy(t_dev + t_mat)
+            self.note_busy(_time.monotonic() - t_busy0)
         if self._quarantine:
             self._quarantine_clear(kind)
         return out
@@ -2240,40 +2235,45 @@ class TpuDriver(RegoDriver):
         re-checks and renders each firing pair exactly."""
         from ..utils.values import freeze
 
-        mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
-                                sig_cache)
-        cand = np.flatnonzero(mask.any(axis=1))
-        if cand.size == 0:
-            return []
+        timers = profiling.timers()
+        with timers.phase("match", kind=kind):
+            mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
+                                    sig_cache)
+            cand = np.flatnonzero(mask.any(axis=1))
+            if cand.size == 0:
+                return []
+            cand_reviews = [reviews[int(i)] for i in cand]
         _t_join0 = time.monotonic()
-        cand_reviews = [reviews[int(i)] for i in cand]
-        if self._join_frz[0] != self._data_rev:
-            self._join_frz = (self._data_rev, {}, {})
-        rev_cache = self._join_frz[1]
-        key_cache = self._join_frz[2].setdefault(kind, {})
-        frz = []
-        for r in cand_reviews:
-            ent = rev_cache.get(id(r))
-            if ent is None or ent[0] is not r:
-                ent = (r, freeze(r))
-                rev_cache[id(r)] = ent
-            frz.append(ent[1])
-        try:
+        failed = None
+        with timers.phase("join", kind=kind):
+            if self._join_frz[0] != self._data_rev:
+                self._join_frz = (self._data_rev, {}, {})
+            rev_cache = self._join_frz[1]
+            key_cache = self._join_frz[2].setdefault(kind, {})
+            frz = []
+            for r in cand_reviews:
+                ent = rev_cache.get(id(r))
+                if ent is None or ent[0] is not r:
+                    ent = (r, freeze(r))
+                    rev_cache[id(r)] = ent
+                frz.append(ent[1])
             try:
                 # always on the device: the audit's join shape is stable
                 # between sweeps (rows bucketed), so it compiles once
                 fires, on_device = jc.fires(
                     frz, self._inventory_tree(target), self._data_gen,
                     device=True, key_cache=key_cache)
+            except Exception as e:
+                failed = e
             finally:
                 # monotonic + finally: an NTP step must not inflate the
                 # duty cycle, and a failed eval still burned wall clock
                 self.note_busy(time.monotonic() - _t_join0)
-        except Exception as e:
+        if failed is not None:
             # transient-capable quarantine, not a permanent demotion —
             # join templates heal the same way compiled ones do
             self._join_compiled.pop(kind, None)
-            self._quarantine_kind(kind, "join-eval", e)
+            self._quarantine_kind(kind, "join-eval", failed)
             return self._audit_interp(target, kind, cons, reviews,
                                       lookup_ns, inventory, trace,
                                       sig_cache)
@@ -2282,27 +2282,29 @@ class TpuDriver(RegoDriver):
         if on_device:  # else no review had a join key: nothing ran
             self._audit_on_device = True
             self.note_eval(kind, "join")
-        hit = np.flatnonzero(fires)
-        if hit.size == 0:
-            return []
-        # join programs are parameter-independent: expand each firing
-        # review to every constraint its match allows
-        sub = mask[cand[hit]]
-        rows_rep, cols = np.nonzero(sub)
-        rows = hit[rows_rep]
-        if trace is None:
-            return self.materialize_pairs(target, cons, cand_reviews,
-                                          rows, cols, inventory,
-                                          cand=cand)
-        out: list[Result] = []
-        for ri, ci in zip(rows, cols):
-            constraint = cons[int(ci)]
-            spec = constraint.get("spec")
-            spec = spec if isinstance(spec, dict) else {}
-            out.extend(self._eval_template_violations(
-                target, constraint, cand_reviews[int(ri)],
-                spec.get("enforcementAction") or "deny", inventory, trace))
-        return out
+        with timers.phase("join_materialize", kind=kind):
+            hit = np.flatnonzero(fires)
+            if hit.size == 0:
+                return []
+            # join programs are parameter-independent: expand each firing
+            # review to every constraint its match allows
+            sub = mask[cand[hit]]
+            rows_rep, cols = np.nonzero(sub)
+            rows = hit[rows_rep]
+            if trace is None:
+                return self.materialize_pairs(target, cons, cand_reviews,
+                                              rows, cols, inventory,
+                                              cand=cand)
+            out: list[Result] = []
+            for ri, ci in zip(rows, cols):
+                constraint = cons[int(ci)]
+                spec = constraint.get("spec")
+                spec = spec if isinstance(spec, dict) else {}
+                out.extend(self._eval_template_violations(
+                    target, constraint, cand_reviews[int(ri)],
+                    spec.get("enforcementAction") or "deny", inventory,
+                    trace))
+            return out
 
     # ------------------------------------------------- audit results delta
 
@@ -2447,25 +2449,28 @@ class TpuDriver(RegoDriver):
         import time as _time
 
         self.note_eval(kind, "interp")
+        timers = profiling.timers()
         out: list[Result] = []
-        mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
-                                sig_cache)
+        with timers.phase("match", kind=kind):
+            mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
+                                    sig_cache)
         n_masked = 0
         t0 = _time.monotonic()
-        for r, review in enumerate(reviews):
-            for c, constraint in enumerate(cons):
-                if not mask[r, c]:
-                    continue
-                n_masked += 1
-                spec = constraint.get("spec")
-                spec = spec if isinstance(spec, dict) else {}
-                enforcement = spec.get("enforcementAction") or "deny"
-                out.extend(self._eval_template_violations(
-                    target, constraint, review, enforcement, inventory, trace))
+        with timers.phase("interp_eval", kind=kind):
+            for r, review in enumerate(reviews):
+                for c, constraint in enumerate(cons):
+                    if not mask[r, c]:
+                        continue
+                    n_masked += 1
+                    spec = constraint.get("spec")
+                    spec = spec if isinstance(spec, dict) else {}
+                    enforcement = spec.get("enforcementAction") or "deny"
+                    out.extend(self._eval_template_violations(
+                        target, constraint, review, enforcement, inventory,
+                        trace))
         # feed the cost model in its own units (masked pairs per second)
         el = _time.monotonic() - t0
         if el > 0:
-            profiling.timers().add("interp_eval", el)
             self.note_busy(el)
         if trace is None and el > 0.005 and n_masked >= 256:
             self._observe("_host_pair_rate", n_masked / el)

@@ -410,3 +410,19 @@ def test_phase_timers():
         pass
     snap = pt.snapshot()
     assert snap["sweep"][1] == 2 and snap["sweep"][0] >= 0.01
+
+    # phases do not nest: one opened inside another suspends it, so each
+    # instant is charged to one phase and the sum stays within the wall
+    pt = PhaseTimers()
+    t0 = _t.monotonic()
+    with pt.phase("outer"):
+        _t.sleep(0.01)
+        with pt.phase("inner"):
+            _t.sleep(0.02)
+        _t.sleep(0.01)
+    wall = _t.monotonic() - t0
+    snap = pt.snapshot()
+    assert snap["outer"][1] == 1 and snap["inner"][1] == 1
+    assert 0.02 <= snap["outer"][0] < 0.03
+    assert 0.02 <= snap["inner"][0]
+    assert snap["outer"][0] + snap["inner"][0] <= wall
