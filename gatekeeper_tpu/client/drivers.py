@@ -724,8 +724,8 @@ class RegoDriver:
         return None
 
     def materialize_pairs(self, target: str, cons: list, pair_reviews: list,
-                          rows, cols, inventory: Any,
-                          cand=None) -> list[Result]:
+                          rows, cols, inventory: Any, cand=None,
+                          fmemo: Optional[dict] = None) -> list[Result]:
         """Batched exact materialization of firing (review, constraint)
         pairs, row-major. Semantically identical to calling
         _eval_template_violations per pair (the audit differential tests
@@ -752,7 +752,12 @@ class RegoDriver:
         cand]), letting witness columns cache across sweeps on the
         stable full review list. Results share constraint/details
         structures (callers treat results as read-only, as they
-        already must for .constraint)."""
+        already must for .constraint).
+
+        `fmemo`, when given, is the arg-pure function memo for this
+        call instead of the kind's memo scoped to `inventory`: a caller
+        rendering against a cut of the inventory passes its own, so the
+        kind's memo for the whole tree is neither replaced nor fed."""
         if not len(rows):
             return []
         kind = cons[0].get("kind")
@@ -792,11 +797,14 @@ class RegoDriver:
                 pmap[id(c)] = pe
             pmemos[ci] = pe[1]
         frozen_inv = self._freeze_inv(inventory)
-        fent = self._fmemo.get(kind)
-        if fent is None or fent[0] is not frozen_inv:
-            fent = (frozen_inv, {})
-            self._fmemo[kind] = fent
-        fmemo = fent[1]
+        if fmemo is None:
+            fent = self._fmemo.get(kind)
+            if fent is None or fent[0] is not frozen_inv:
+                fent = (frozen_inv, {})
+                self._fmemo[kind] = fent
+            fmemo = fent[1]
+        # the head-witness memo is keyed on the values of data-free
+        # literals (codegen _head_memo_plan), so it serves any tree
         hm = self._hmemo.get(kind)
         if hm is None:
             hm = self._hmemo[kind] = {}

@@ -709,6 +709,7 @@ DEMOTION_REASONS = ("audit-eval", "review-eval", "join-eval",
 COMPILE_OUTCOMES = ("ok", "error")
 AUDIT_SWEEP_PATHS = ("incremental", "full_resync", "full", "stream")
 MATERIALIZE_PATHS = ("vectorized", "exact", "capped")
+JOIN_RENDER_SCOPES = ("holders", "inventory")
 CACHE_OUTCOMES = ("hit", "miss")
 STREAM_FLUSH_OUTCOMES = ("ok", "error", "skipped")
 PREVIEW_OUTCOMES = ("ok", "error", "invalid")
@@ -1170,6 +1171,19 @@ def report_materialize_pairs(path: str, n: int) -> None:
         REGISTRY.counter_add("gatekeeper_tpu_audit_materialize_pairs_total",
                              "Materialized firing pairs by message path",
                              float(n), path=path)
+
+
+def report_join_render(scope: str, n: int) -> None:
+    """Firing inventory-join pairs the audit rendered, by the tree their
+    messages were rendered against: "holders" (only the objects holding
+    one of the review's join keys, ir/join.py holder_tree), "inventory"
+    (the whole inventory: the exactness gate could not prove the cut)."""
+    if scope not in JOIN_RENDER_SCOPES:
+        scope = LABEL_FOLD
+    if n > 0:
+        REGISTRY.counter_add("gatekeeper_tpu_audit_join_render_total",
+                             "Rendered firing join pairs by inventory scope",
+                             float(n), scope=scope)
 
 
 def report_msg_template_cache(outcome: str) -> None:

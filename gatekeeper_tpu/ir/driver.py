@@ -2292,9 +2292,9 @@ class TpuDriver(RegoDriver):
             rows_rep, cols = np.nonzero(sub)
             rows = hit[rows_rep]
             if trace is None:
-                return self.materialize_pairs(target, cons, cand_reviews,
-                                              rows, cols, inventory,
-                                              cand=cand)
+                return self._render_join(target, jc, cons, cand_reviews,
+                                         frz, rows, cols, inventory, cand,
+                                         key_cache)
             out: list[Result] = []
             for ri, ci in zip(rows, cols):
                 constraint = cons[int(ci)]
@@ -2305,6 +2305,40 @@ class TpuDriver(RegoDriver):
                     spec.get("enforcementAction") or "deny", inventory,
                     trace))
             return out
+
+    def _render_join(self, target, jc, cons, cand_reviews, frz, rows, cols,
+                     inventory, cand, key_cache) -> list[Result]:
+        """Render a join kind's firing pairs (review-major rows), each
+        review against the holders of its join keys (JoinCompiled.
+        holder_tree) where that is provably exact, else against the
+        whole inventory. Results come in row order either way."""
+        from ..control.metrics import report_join_render
+
+        # arg-pure entries depend on their arguments alone; a memo of
+        # the holder calls' own keeps the kind's whole-tree memo intact
+        fmemo: dict = {}
+        # [tree or None (whole inventory), lo, hi]: one run per review,
+        # neighbouring whole-inventory reviews rendered in one call
+        runs: list = []
+        bounds = np.flatnonzero(np.diff(rows)) + 1
+        for lo, hi in zip(np.r_[0, bounds], np.r_[bounds, len(rows)]):
+            tree = jc.holder_tree(inventory, self._data_gen,
+                                  frz[int(rows[lo])], key_cache)
+            if tree is None and runs and runs[-1][0] is None:
+                runs[-1][2] = hi
+            else:
+                runs.append([tree, lo, hi])
+        out: list[Result] = []
+        n_pairs = {"holders": 0, "inventory": 0}
+        for tree, lo, hi in runs:
+            n_pairs["inventory" if tree is None else "holders"] += hi - lo
+            out.extend(self.materialize_pairs(
+                target, cons, cand_reviews, rows[lo:hi], cols[lo:hi],
+                inventory if tree is None else tree, cand=cand,
+                fmemo=None if tree is None else fmemo))
+        for scope, n in n_pairs.items():
+            report_join_render(scope, int(n))
+        return out
 
     # ------------------------------------------------- audit results delta
 

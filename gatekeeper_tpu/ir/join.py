@@ -31,7 +31,9 @@ literal (rather than bound `other := ...` first) is extracted into a
 synthesized generator binding. The join decision is exact except in the
 degenerate case of distinct inventory objects sharing one identity key
 (then it may only OVER-fire); host materialization re-checks every firing
-pair, the same authority contract as ir/evaljax.py.
+pair, the same authority contract as ir/evaljax.py. It renders a firing
+review against the holders of its join keys alone (`holder_tree`), where
+that provably gives what the whole inventory gives.
 """
 
 from __future__ import annotations
@@ -144,6 +146,14 @@ class JoinClause:
     rev_ident: list   # complete-rule names: [i1, i2, ...] per group
     inv_entries: str  # partial-set rule: {[[path...], [k...]]}
     inv_ident: list   # partial-set rules: {[[path...], [i...]]} per group
+    # the generator ref's segments after "inventory": (False, scalar) or
+    # (True, i), the i-th entry of an inv_entries path — enough to put a
+    # key holder back at its place in the inventory tree
+    gen_path: tuple = ()
+    # nothing but the generator reads data, head and head-only literals
+    # included: the clause renders the same against the holders of a
+    # review's keys as against the whole inventory
+    holders_exact: bool = False
 
 
 @dataclass
@@ -151,6 +161,10 @@ class JoinProgram:
     kind: str
     module: A.Module            # helpers + synthesized join rules
     clauses: list[JoinClause] = field(default_factory=list)
+
+    @property
+    def holders_exact(self) -> bool:
+        return all(c.holders_exact for c in self.clauses)
 
 
 def _rule_flags(rules_by_name: dict) -> dict:
@@ -442,6 +456,8 @@ def _compile_clause(rule: A.Rule, rules_by_name: dict, idx: int,
     head_names: set = set()
     _names(rule.key, head_names)
     body = _drop_head_only(list(rule.body), head_names, rules_by_name)
+    head_only = [lit for lit in rule.body
+                 if not any(lit is kept for kept in body)]
     # `some ns, apiv, name` declarations scope vars the generator walk
     # names anyway — they carry no constraints of their own
     body = [lit for lit in body if not isinstance(lit.expr, A.SomeDecl)]
@@ -475,14 +491,17 @@ def _compile_clause(rule: A.Rule, rules_by_name: dict, idx: int,
     # tuple is always fully bound)
     path_vars: list[str] = []
     new_args: list = []
+    gen_path: list = []
     for k, a in enumerate(inv_ref.args[1:]):  # skip the "inventory" segment
         if isinstance(a, A.Var):
             nm = a.name
             if nm.startswith("$wc"):
                 nm = f"__jw{idx}_{k}"
+            gen_path.append((True, len(path_vars)))
             path_vars.append(nm)
             new_args.append(A.Var(nm))
         elif isinstance(a, A.Scalar):
+            gen_path.append((False, a.value))
             new_args.append(a)
         else:
             raise Uncompilable("join-generator", "complex inventory path segment")
@@ -692,6 +711,14 @@ def _compile_clause(rule: A.Rule, rules_by_name: dict, idx: int,
 
     if not join_pairs:
         raise Uncompilable("join-shape", "no join predicate")
+    # the kept literals were held to no data read above; the head and the
+    # literals only it uses were not (a `count(data.inventory...)` in msg)
+    head_reads = var_reads(rule.key)
+    for lit in head_only:
+        if lit.withs:
+            head_reads.add("data")
+        elif not isinstance(lit.expr, A.SomeDecl):
+            reads_of(lit.expr, head_reads)
 
     # ---- synthesized rules ------------------------------------------
     path_tuple = A.ArrayLit(tuple(A.Var(v) for v in path_vars))
@@ -723,7 +750,8 @@ def _compile_clause(rule: A.Rule, rules_by_name: dict, idx: int,
                             A.ArrayLit(tuple(p[0] for p in pairs)))),
             body=(gen_lit,) + tuple(inv_lits)))
     return JoinClause(rev_keys=rk, rev_ident=ris, inv_entries=ie,
-                      inv_ident=iis)
+                      inv_ident=iis, gen_path=tuple(gen_path),
+                      holders_exact="data" not in head_reads)
 
 
 def compile_join(module: A.Module, kind: str) -> JoinProgram:
@@ -761,6 +789,36 @@ def _canon_sid(strtab, v) -> int:
     if isinstance(v, str):
         return strtab.intern("k:s:" + v)
     return strtab.intern("k:j:" + json.dumps(thaw(v), sort_keys=True))
+
+
+def _strings_only(v) -> bool:
+    """A string, or arrays of them: two such keys share a sid exactly
+    when Rego equality joins them. Numbers, objects and sets can part
+    in canonical JSON where Rego equates them."""
+    if isinstance(v, str):
+        return True
+    return isinstance(v, tuple) and all(_strings_only(x) for x in v)
+
+
+def _keep_path(want: dict, path) -> None:
+    """Mark one tree path in a nested {segment: sub-want} dict, where
+    None keeps the whole branch."""
+    node = want
+    for seg in path[:-1]:
+        node = node.setdefault(seg, {})
+        if node is None:  # an ancestor is already kept whole
+            return
+    node[path[-1]] = None
+
+
+def _prune(node, want):
+    """`node` cut to the branches `want` names, sharing every kept leaf.
+    A branch below a non-object node is kept whole."""
+    from ..utils.values import FrozenDict
+
+    if want is None or not isinstance(node, FrozenDict):
+        return node
+    return FrozenDict((k, _prune(node[k], w)) for k, w in want.items())
 
 
 class JoinCompiled:
@@ -810,8 +868,9 @@ class JoinCompiled:
     def inv_tables(self, inventory_tree, data_gen) -> list:
         """Per clause: (U sorted unique key sids, CNT objects per key,
         SIK [G, K] per-identity-group sid when CNT==1 else IK_MULTI,
-        host dict). G >= 1 always — a template without an identity fn
-        gets one group of missing sentinels, which never match."""
+        host dict, holders {key sid: [inv_entries paths]}). G >= 1
+        always — a template without an identity fn gets one group of
+        missing sentinels, which never match."""
         cache_key = (data_gen, id(inventory_tree))
         hit = self._inv_cache.get(cache_key)
         # the entry pins the tree, so an id() hit can only be the same
@@ -838,27 +897,26 @@ class JoinCompiled:
                             path, [IK_INV_MISSING] * G)
                         ent[g] = self.strtab.intern(
                             "i:" + json.dumps(thaw(ident), sort_keys=True))
-            by_key: dict[int, list] = {}
+            holders: dict[int, list] = {}
             if entries is not UNDEF:
                 per_obj: dict = {}
                 for path, key in entries:
                     per_obj.setdefault(path, set()).add(
                         _canon_sid(self.strtab, key))
-                missing = (IK_INV_MISSING,) * G
                 for path, ksids in per_obj.items():
-                    ik = tuple(idents.get(path, missing))
                     for ks in ksids:
-                        by_key.setdefault(ks, []).append(ik)
-            u = np.array(sorted(by_key), dtype=np.int64)
-            cnt = np.array([len(by_key[k]) for k in u], dtype=np.int32)
+                        holders.setdefault(ks, []).append(path)
+            u = np.array(sorted(holders), dtype=np.int64)
+            cnt = np.array([len(holders[k]) for k in u], dtype=np.int32)
             sik = np.full((G, len(u)), IK_MULTI, dtype=np.int64)
+            missing = [IK_INV_MISSING] * G
             for j, k in enumerate(u):
-                holders = by_key[k]
-                if len(holders) == 1:
-                    sik[:, j] = holders[0]
+                paths = holders[k]
+                if len(paths) == 1:
+                    sik[:, j] = idents.get(paths[0], missing)
             host = {int(k): (int(c_), tuple(int(s) for s in sik[:, j]))
                     for j, (k, c_) in enumerate(zip(u, cnt))}
-            tabs.append((u, cnt, sik, host))
+            tabs.append((u, cnt, sik, host, holders))
         # stale generations (and their device tensors) can't be reused;
         # drop them so long-running audits don't accumulate tables
         if any(k[0] != data_gen for k in self._inv_cache):
@@ -879,8 +937,9 @@ class JoinCompiled:
         return fn(FrozenDict((("review", frz_review),)), frozen_empty)
 
     def review_keys(self, clause_i: int, frz_review) -> tuple:
-        """(key sids list, per-group ident sid tuple) for one review;
-        empty list when the review-side filters fail."""
+        """(key sids list, per-group ident sid tuple, every key made of
+        strings only) for one review; empty list when the review-side
+        filters fail."""
         from ..rego.interp import UNDEF
         from ..utils.values import FrozenDict
 
@@ -889,7 +948,7 @@ class JoinCompiled:
         empty = FrozenDict()
         ks = self._rev_eval(fk, frz_review, empty)
         if ks is UNDEF or not ks:
-            return [], (IK_REV_MISSING,) * G
+            return [], (IK_REV_MISSING,) * G, True
         sids = sorted({_canon_sid(self.strtab, k) for k in ks})
         iks = [IK_REV_MISSING] * G
         for g, fi in enumerate(fis):
@@ -897,7 +956,40 @@ class JoinCompiled:
             if iv is not UNDEF:
                 iks[g] = self.strtab.intern(
                     "i:" + json.dumps(thaw(iv), sort_keys=True))
-        return sids, tuple(iks)
+        return sids, tuple(iks), all(_strings_only(k) for k in ks)
+
+    def holder_tree(self, inventory_tree, data_gen, frz_review,
+                    key_cache: Optional[dict] = None):
+        """The inventory tree cut to the objects that hold one of the
+        review's join keys, in any clause: same nesting, the same frozen
+        leaf instances. Rendering the review's violations against it
+        gives what the whole tree gives, since no other object can pass
+        a join equality. None where that is not provable: some clause
+        reads data outside its generator, or a key is not made of
+        strings only."""
+        if not self.prog.holders_exact:
+            return None
+        want: dict = {}
+        tabs = self.inv_tables(inventory_tree, data_gen)
+        for ci, (clause, tab) in enumerate(zip(self.prog.clauses, tabs)):
+            holders = tab[4]
+            if not holders:
+                continue
+            hit = key_cache.get((ci, id(frz_review))) \
+                if key_cache is not None else None
+            if hit is None:
+                hit = self.review_keys(ci, frz_review)
+            ks, _ik, strings_only = hit
+            if not strings_only:
+                return None
+            for k in ks:
+                for path in holders.get(k, ()):
+                    full = [path[v] if is_var else v
+                            for is_var, v in clause.gen_path]
+                    if not full:  # the generator binds the whole tree
+                        return inventory_tree
+                    _keep_path(want, full)
+        return _prune(inventory_tree, want)
 
     # ------------------------------------------------------------ fires
 
@@ -909,13 +1001,13 @@ class JoinCompiled:
               device: bool, key_cache: Optional[dict] = None) -> tuple:
         """(bool[N], ran_on_device): does some OTHER inventory object
         share a join key, probed on the device or in a host dict.
-        key_cache (id(review) -> per-clause (keys, ident)), valid for one
+        key_cache ((clause, id(review)) -> review_keys), valid for one
         data generation, makes steady-state audits skip re-extraction."""
         tabs = self.inv_tables(inventory_tree, data_gen)
         n = len(frz_reviews)
         out = np.zeros(n, dtype=bool)
         on_device = False
-        for ci, (u, cnt, sik, host) in enumerate(tabs):
+        for ci, (u, cnt, sik, host, _holders) in enumerate(tabs):
             if not len(u):
                 continue
             G = sik.shape[0]
@@ -930,7 +1022,7 @@ class JoinCompiled:
                     hit = self.review_keys(ci, rv)
                     if key_cache is not None:
                         key_cache[(ci, id(rv))] = hit
-                ks, ik = hit
+                ks, ik, _ = hit
                 keys.append(ks)
                 iks[r, :] = ik
                 hmax = max(hmax, len(ks))
