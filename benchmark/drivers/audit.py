@@ -9,9 +9,9 @@ Set-up: generate the library from the seed, ingest it, run the first
 audit (which loads every program from the compile cache) and wait out
 background compiles. Window: audits start until --seconds have passed;
 the last one started runs to its end. Check: every audit of the window
-against the configuration's reference; the window ran on the device
-("single" path, every kind with objects to judge dispatched there,
-nothing demoted) and compiled nothing.
+against the configuration's reference; the window ran on the device (on
+the path the cell's chips imply, every kind with objects to judge
+dispatched there, nothing demoted) and compiled nothing.
 """
 
 from __future__ import annotations
@@ -69,6 +69,21 @@ def mismatches(got: Counter, want: Counter) -> int:
     return sum(((got - want) + (want - got)).values())
 
 
+def expected_path(chips: int) -> str:
+    """The audit path (`TpuDriver.last_audit_path`) a cell's chips imply:
+    on more than one chip the program shards the sweep over a data axis
+    of every chip it sees."""
+    return "single" if chips == 1 else f"mesh(data={chips})"
+
+
+def check_paths(checks, chips: int, paths: list) -> None:
+    """Every window audit took the path the cell's chips imply."""
+    want = expected_path(chips)
+    checks.max("audits_not_single_path" if chips == 1
+               else "audits_not_mesh_path",
+               sum(p != want for p in paths), 0)
+
+
 def kinds_with_objects(lib: dict) -> set:
     """The constraint kinds that match some object of the inventory."""
     seen = {(group_version(o)[0], o.get("kind")) for o in lib["inventory"]}
@@ -85,7 +100,7 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
     from gatekeeper_tpu.utils import profiling
 
     t0 = time.monotonic()
-    lib = library.build(cell.config, seed)
+    lib = library.build(cell.config, seed, root=cell.root)
     gen_s = time.monotonic() - t0
 
     t0 = time.monotonic()
@@ -117,6 +132,7 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
     xla_w0 = harness.xla_cache()
     counts0 = driver.eval_counts()
     phases0 = profiling.timers().snapshot()
+    gc0 = gc.get_stats()
     # equal answers are kept once: {packed answer: audits that gave it}
     walls, answers, paths, sizes = [], Counter(), [], []
     if prof:
@@ -136,6 +152,9 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
                 answers[pack(results)] += 1
                 del results
     t_end = time.monotonic()
+    # the collector's passes per generation inside the window
+    gc_passes = [b["collections"] - a["collections"]
+                 for a, b in zip(gc0, gc.get_stats())]
     if prof:
         prof.stop()
     phases = profiling.PhaseTimers.diff(phases0,
@@ -146,8 +165,9 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
     hl = harness.health(driver)
     peak = harness.memory_peak_bytes(cell.chips)
     window_compiles = xla_w1.get("requests", 0) - xla_w0.get("requests", 0)
-    harness.say("window", audits=len(walls), walls_s=walls[:50],
-                paths=Counter(paths), seconds=t_end - t_window,
+    harness.say("window", audits=len(walls), walls_s=walls,
+                gc_passes=gc_passes, paths=Counter(paths),
+                seconds=t_end - t_window,
                 phases_s=phases, served=served,
                 violations=sorted(Counter(sizes).items()),
                 xla_cache_requests=window_compiles, health=hl)
@@ -180,8 +200,7 @@ def run(cell, seed: int, seconds: float, trace: bool, control: bool,
     checks = harness.Checks()
     checks.max("result_mismatches", sum(bad), 0)
     checks.min("audits_checked", len(bad), 1)
-    checks.max("audits_not_single_path",
-               sum(p != "single" for p in paths), 0)
+    check_paths(checks, cell.chips, paths)
     checks.max("kinds_off_device", len(on_host) + len(missing), 0)
     checks.max("demoted_or_quarantined",
                len(hl["demotions"]) + len(hl["quarantined"])

@@ -1,21 +1,23 @@
 """A configuration's library from its file and the run's seed: templates,
-constraints and inventory, each drawn by a generator named in the file.
+constraints and inventory, each entry drawn by a generator that a file of
+its own holds, found by the name the entry gives:
 
     "templates":   paths (JSON or YAML) relative to the configuration
-    "constraints": [{"generator": "general", "match_by_kind"}]: one
-                   constraint per GENERAL_CONSTRAINTS entry, matched
-                   as match_by_kind says
-    "inventory":   [{"generator": "cluster", "counts", "breakers",
-                     "seed_offset"}]: workload.cluster_fixed
+    "constraints": [{"generator": g, ...}]: gen/constraints/<g>.py
+    "inventory":   [{"generator": g, ...}]: gen/inventory/<g>.py
+
+Each generator file has `build(entry, config, seed) -> list`, and reads
+its own keys from the entry. A configuration with new constraints or a
+new inventory adds a generator file beside these; this module does not
+change.
 """
 
 from __future__ import annotations
 
-import copy
 import json
 import os
 
-from benchmark.gen import workload
+from benchmark import spec
 
 
 def _load(path: str) -> dict:
@@ -27,36 +29,28 @@ def _load(path: str) -> dict:
         return yaml.safe_load(f)
 
 
-def _constraints(entry: dict, seed: int) -> list:
-    gen = entry["generator"]
-    if gen == "general":
-        out = []
-        for kind, name, params in workload.GENERAL_CONSTRAINTS:
-            spec = {"match": copy.deepcopy(entry["match_by_kind"][kind])}
-            if params:
-                spec["parameters"] = copy.deepcopy(params)
-            out.append({"apiVersion": "constraints.gatekeeper.sh/v1beta1",
-                        "kind": kind, "metadata": {"name": name},
-                        "spec": spec})
-        return out
-    raise ValueError(f"unknown constraint generator {gen!r}")
+def _generator(root: str, group: str, name: str):
+    """The module of gen/<group>/<name>.py under the benchmark root."""
+    path = os.path.join(root, "gen", group, name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"unknown {group} generator {name!r}: no file "
+                         f"{path}")
+    return spec.load_module(path, f"bench_gen_{group}_"
+                            + name.replace("-", "_").replace(".", "_"))
 
 
-def _inventory(entry: dict, seed: int) -> list:
-    gen = entry["generator"]
-    s = seed + entry.get("seed_offset", 0)
-    if gen == "cluster":
-        return workload.cluster_fixed(entry["counts"], entry["breakers"], s)
-    raise ValueError(f"unknown inventory generator {gen!r}")
+def _draw(root: str, group: str, config: dict, seed: int) -> list:
+    return [x for e in config[group]
+            for x in _generator(root, group, e["generator"]).build(
+                e, config, seed)]
 
 
-def build(config: dict, seed: int) -> dict:
-    """{"templates", "constraints", "inventory"} for the seed."""
+def build(config: dict, seed: int, root: str = spec.HERE) -> dict:
+    """{"templates", "constraints", "inventory"} for the seed; `root` is
+    the benchmark root the generators are found under."""
     return {
         "templates": [_load(os.path.join(config["dir"], p))
                       for p in config["templates"]],
-        "constraints": [c for e in config["constraints"]
-                        for c in _constraints(e, seed)],
-        "inventory": [o for e in config["inventory"]
-                      for o in _inventory(e, seed)],
+        "constraints": _draw(root, "constraints", config, seed),
+        "inventory": _draw(root, "inventory", config, seed),
     }

@@ -9,6 +9,11 @@ and entries only:
                                  names drivers/<driver>.py
     metrics/<metric>.py       -> one reader per metric, end-to-end or
                                  per-layer: read(obs) -> number or None
+    gen/constraints/<g>.py    -> a configuration's constraint entry
+                                 {"generator": g, ...}: build(entry,
+                                 config, seed) -> constraints
+    gen/inventory/<g>.py      -> the same for an inventory entry:
+                                 build(entry, config, seed) -> objects
 """
 
 from __future__ import annotations

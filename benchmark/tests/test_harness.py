@@ -1,7 +1,9 @@
 """The harness end to end on the CPU at tiny sizes: a cell found by its
-name alone, the contract's last line, no result without a TPU, and the
-reference agreeing with the program."""
+name alone, a configuration made of new files alone, the audit path a
+cell's chips imply, the contract's last line, no result without a TPU,
+and the reference agreeing with the program."""
 
+import hashlib
 import json
 import os
 import subprocess
@@ -9,8 +11,11 @@ import sys
 
 import pytest
 
-from benchmark import run, spec
+from benchmark import harness, run, spec
 from benchmark.tests import tiny
+
+audit_driver = spec.load_module(os.path.join(tiny.BENCH, "drivers",
+                                             "audit.py"), "bench_driver_audit")
 
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
                  "checks"]
@@ -76,6 +81,198 @@ def test_dummy_cell_found_by_name(tmp_path, capsys):
     line = last_line(capsys)
     assert line["metrics"] == {"things": {"value": 15, "unit": "n"}}
     assert line["correct"] is True
+
+
+TOY_TEMPLATE = """\
+apiVersion: templates.gatekeeper.sh/v1beta1
+kind: ConstraintTemplate
+metadata:
+  name: k8spspprivilegedcontainer
+spec:
+  crd:
+    spec:
+      names:
+        kind: K8sPSPPrivilegedContainer
+  targets:
+    - target: admission.k8s.gatekeeper.sh
+      rego: |
+        package k8spspprivileged
+
+        pod_containers[c] {
+          c := input.review.object.spec.containers[_]
+        }
+
+        pod_containers[c] {
+          c := input.review.object.spec.initContainers[_]
+        }
+
+        violation[{"msg": msg, "details": {}}] {
+          c := pod_containers[_]
+          c.securityContext.privileged
+          msg := sprintf("Privileged container is not allowed: %v, securityContext: %v", [c.name, c.securityContext])
+        }
+"""
+
+TOY_CONSTRAINTS = """\
+def build(entry, config, seed):
+    return [{"apiVersion": "constraints.gatekeeper.sh/v1beta1",
+             "kind": "K8sPSPPrivilegedContainer",
+             "metadata": {"name": entry["name"]},
+             "spec": {"match": {"kinds": [{"apiGroups": [""],
+                                           "kinds": ["Pod"]}]}}}]
+"""
+
+TOY_INVENTORY = """\
+import random
+
+def build(entry, config, seed):
+    n = entry["count"]
+    bad = set(random.Random(seed).sample(range(n), entry["privileged"]))
+    return [{"apiVersion": "v1", "kind": "Pod",
+             "metadata": {"name": f"p-{i}", "namespace": "default"},
+             "spec": {"containers": [{
+                 "name": "main", "image": "nginx",
+                 "securityContext": {"privileged": i in bad}}]}}
+            for i in range(n)]
+"""
+
+TOY_REFERENCE = """\
+from collections import Counter
+
+from benchmark.refcommon import fmt_v, ident, matches, review_of
+
+
+def audit(objects, constraints, cap_per_constraint=None):
+    out = Counter()
+    for obj in objects:
+        review = review_of(obj)
+        for c in constraints:
+            if not matches(c["spec"].get("match") or {}, review):
+                continue
+            for field in ("containers", "initContainers"):
+                for ct in obj["spec"].get(field, []):
+                    sc = ct.get("securityContext") or {}
+                    if sc.get("privileged"):
+                        out[(ident(obj), c["metadata"]["name"],
+                             "Privileged container is not allowed: "
+                             f"{ct['name']}, securityContext: "
+                             f"{fmt_v(sc)}")] += 1
+    return out
+"""
+
+
+def _tree_digest(top: str) -> dict:
+    out = {}
+    for d, dirs, files in os.walk(top):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for f in files:
+            with open(os.path.join(d, f), "rb") as fh:
+                out[os.path.relpath(os.path.join(d, f), top)] = \
+                    hashlib.sha256(fh.read()).hexdigest()
+    return out
+
+
+def test_new_configuration_no_harness_edit(tmp_path, capsys):
+    """A configuration with its own constraint generator, inventory
+    generator, template and reference, run through the real `full-sweep`
+    mix and audit driver: all new files and entries, no harness file
+    touched."""
+    before = _tree_digest(tiny.BENCH)
+    b = tmp_path / "benchmark"
+    (b / "gen" / "constraints").mkdir(parents=True)
+    (b / "gen" / "inventory").mkdir()
+    for sub in ("drivers", "metrics", "traffic"):
+        os.symlink(os.path.join(tiny.BENCH, sub), b / sub)
+    cdir = b / "configs" / "toy"
+    (cdir / "templates").mkdir(parents=True)
+    (cdir / "templates" / "privileged.yaml").write_text(TOY_TEMPLATE)
+    (cdir / "reference.py").write_text(TOY_REFERENCE)
+    (b / "gen" / "constraints" / "privileged.py").write_text(
+        TOY_CONSTRAINTS)
+    (b / "gen" / "inventory" / "pods.py").write_text(TOY_INVENTORY)
+    (cdir / "config.json").write_text(json.dumps({
+        "name": "toy", "templates": ["templates/privileged.yaml"],
+        "constraints": [{"generator": "privileged",
+                         "name": "no-privileged"}],
+        "inventory": [{"generator": "pods", "count": 60,
+                       "privileged": 6}],
+        "reference": "reference.py"}))
+    with open(os.path.join(tiny.CHECKOUT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"] = [{"name": "toy", "source": "x",
+                         "file": "benchmark/configs/toy/config.json",
+                         "reduced": [], "why": "x"}]
+    bench["workloads"] = [{"name": "audit.toy", "config": "toy",
+                           "traffic": "full-sweep", "chips": 1,
+                           "why": "x"}]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = ["audit.toy"]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    rc = run.main(["--workload", "audit.toy", "--seed", "2147483659",
+                   "--seconds", "1", "--trace", "0"],
+                  root=str(tmp_path), require_chip=False)
+    assert rc == 0
+    out = capsys.readouterr().out
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["correct"] is True
+    assert line["attempted"] >= 1
+    assert set(line["metrics"]) == {"audit_s", "setup_s"}
+    assert '"objects": 60' in out and '"violations": 6' in out
+    assert _tree_digest(tiny.BENCH) == before
+
+
+@pytest.mark.parametrize("chips,paths,off", [
+    (1, ["single", "single"], 0),
+    (1, ["single", "mesh(data=4)"], 1),
+    (4, ["mesh(data=4)", "mesh(data=4)"], 0),
+    (4, ["mesh(data=4)", "single"], 1),
+    (4, ["single", "single"], 2),
+])
+def test_expected_path(chips, paths, off):
+    """1 chip expects `single`, 4 chips `mesh(data=4)`; the check keeps
+    its name on one chip."""
+    checks = harness.Checks()
+    audit_driver.check_paths(checks, chips, paths)
+    name = "audits_not_single_path" if chips == 1 else \
+        "audits_not_mesh_path"
+    assert checks.items == {name: {"value": off, "limit": 0,
+                                   "rule": "max"}}
+    assert checks.ok() is (off == 0)
+
+
+MESH_RUN = """\
+import json, sys
+sys.path.insert(0, {checkout!r})
+from benchmark import run
+from benchmark.tests import tiny
+root = tiny.make_root({tmp!r}, extra_cells=[dict(
+    name="audit.general-thresholds.mesh4", config="general-thresholds",
+    traffic="full-sweep", chips=4, why="x")])
+for w in ("audit.general-thresholds.mesh4", "audit.general-thresholds"):
+    run.main(["--workload", w, "--seed", "2147483653", "--seconds", "1",
+              "--trace", "0"], root=root, require_chip=False)
+"""
+
+
+def test_mesh_cell_judged_on_a_cpu_mesh(tmp_path):
+    """With four devices the program shards the audit itself: a 4-chip
+    cell's audits pass the path check, and a 1-chip cell's fail it."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               GATEKEEPER_TPU_MESH_MIN_REVIEWS="64")
+    p = subprocess.run(
+        [sys.executable, "-c", MESH_RUN.format(checkout=tiny.CHECKOUT,
+                                               tmp=str(tmp_path))],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    mesh4, single = [json.loads(ln) for ln in p.stdout.splitlines()
+                     if ln.startswith("{")]
+    assert mesh4["checks"]["audits_not_mesh_path"]["value"] == 0
+    assert mesh4["correct"] is True
+    assert mesh4["checks"]["kinds_off_device"]["value"] == 0
+    assert single["checks"]["audits_not_single_path"]["value"] > 0
+    assert single["correct"] is False
 
 
 def test_real_cells_resolve():

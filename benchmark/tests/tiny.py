@@ -1,6 +1,9 @@
 """A benchmark root at a size a CPU test can hold: the real BENCHMARK.json,
-drivers, metrics and traffic mixes, with each configuration's counts cut
-(templates and references are the real ones, by absolute path)."""
+drivers, metrics, traffic mixes and generators, and each configuration's
+directory linked entry by entry, with only its file rewritten with the
+counts cut. A configuration's relative paths (templates, references, any
+file of its own) so resolve in the tiny root without this module knowing
+its keys."""
 
 from __future__ import annotations
 
@@ -24,31 +27,38 @@ def _cut(entries: list) -> None:
                              for k, n in e["counts"].items()}
 
 
+def _link(src: str, dst: str) -> None:
+    if not os.path.lexists(dst):
+        os.symlink(src, dst)
+
+
 def make_root(tmp: str, extra_cells: list | None = None) -> str:
     """Write <tmp>/BENCHMARK.json and <tmp>/benchmark/ (links to the
-    real drivers, metrics and traffic mixes); return tmp."""
+    real drivers, metrics, traffic mixes and generators, and to every
+    entry of each configuration's directory but its cut file); return
+    tmp."""
     with open(os.path.join(CHECKOUT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     broot = os.path.join(tmp, "benchmark")
     os.makedirs(broot, exist_ok=True)
-    for sub in ("drivers", "metrics", "traffic"):
-        dst = os.path.join(broot, sub)
-        if not os.path.exists(dst):
-            os.symlink(os.path.join(BENCH, sub), dst)
+    for sub in ("drivers", "metrics", "traffic", "gen"):
+        _link(os.path.join(BENCH, sub), os.path.join(broot, sub))
     for c in bench["configs"]:
         src = os.path.join(CHECKOUT, c["file"])
+        dst = os.path.join(tmp, c["file"])
+        os.makedirs(os.path.dirname(dst), exist_ok=True)
+        for entry in os.listdir(os.path.dirname(src)):
+            _link(os.path.join(os.path.dirname(src), entry),
+                  os.path.join(os.path.dirname(dst), entry))
         with open(src) as f:
             cfg = json.load(f)
-        d = os.path.dirname(src)
-        cfg["templates"] = [os.path.join(d, p) for p in cfg["templates"]]
-        cfg["reference"] = os.path.join(d, cfg["reference"])
         _cut(cfg["constraints"])
         _cut(cfg["inventory"])
-        rel = os.path.join("configs", c["name"], "config.json")
-        os.makedirs(os.path.dirname(os.path.join(tmp, rel)), exist_ok=True)
-        with open(os.path.join(tmp, rel), "w") as f:
+        # the link to the real file goes first: writing through it would
+        # change the real configuration
+        os.remove(dst)
+        with open(dst, "w") as f:
             json.dump(cfg, f)
-        c["file"] = rel
     bench["workloads"] += extra_cells or []
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
