@@ -415,6 +415,8 @@ class TpuDriver(RegoDriver):
         # throughput (~130k pairs/s on this class of host); the EMA
         # refines it from real batches and audits
         self._host_pair_rate: float = 100_000.0
+        # host seconds the current audit spent interpreting (_warm_gate)
+        self._audit_host_s = 0.0
         self._dev_skips = 0
         # multi-device: audits shard over the mesh's "data" axis (the
         # object dimension — SURVEY §2.5's batch-parallel sweep) when
@@ -1576,6 +1578,7 @@ class TpuDriver(RegoDriver):
         # host materialization (the pipeline window), which would
         # overstate device latency and bias the cost model to the host
         self._lat_sampled = False
+        self._audit_host_s = 0.0
         if not constraints:
             return []
         lookup_ns = self._namespace_lookup(target)
@@ -1602,6 +1605,10 @@ class TpuDriver(RegoDriver):
         # bound for pathological template counts
         window = 64
         delta_served: set = set()
+        # cold kinds the host cannot afford this audit: their compiles
+        # run in the background while the other kinds dispatch, and they
+        # dispatch once the loop has started every compile
+        deferred: list[str] = []
 
         # per-kind completion observer (the streaming status writer):
         # fired as soon as a kind's results exist, so constraint-status
@@ -1679,7 +1686,10 @@ class TpuDriver(RegoDriver):
                                                      sig_cache)
                     emit(k0)
                 st = self._audit_dispatch(target, kind, ct, cons, reviews,
-                                          lookup_ns, sig_cache)
+                                          lookup_ns, sig_cache, defer=True)
+                if st is not None and st[0] == "cold":
+                    deferred.append(kind)
+                    continue
                 if st is not None:
                     pending.append((kind, st))
                     continue
@@ -1706,6 +1716,16 @@ class TpuDriver(RegoDriver):
             by_res[kind] = self._audit_interp(target, kind, cons, reviews,
                                               lookup_ns, inventory, trace,
                                               sig_cache)
+            emit(kind)
+        for kind in deferred:
+            st = self._audit_dispatch(target, kind, cts[kind], by_kind[kind],
+                                      reviews, lookup_ns, sig_cache)
+            if st is not None:
+                pending.append((kind, st))
+                continue
+            by_res[kind] = self._audit_interp(target, kind, by_kind[kind],
+                                              reviews, lookup_ns, inventory,
+                                              trace, sig_cache)
             emit(kind)
         for kind, st in pending:
             by_res[kind] = self._audit_consume(target, kind, st,
@@ -1740,10 +1760,17 @@ class TpuDriver(RegoDriver):
     # mesh dispatch only pays off once per-shard slabs are substantial
     MESH_MIN_REVIEWS = 8192
 
-    # async warm-up serves the host path only while its estimated cost
-    # stays under this; beyond it, blocking on the compile once is
-    # cheaper than minutes of interpretation
+    # async warm-up serves a batch from the host path only while its
+    # estimated cost stays under this; beyond it, blocking on the
+    # compile once is cheaper than minutes of interpretation
     ASYNC_WARM_MAX_HOST_S = 30.0
+    # the same limit for a whole audit: its cold kinds are served from
+    # the host only while the host time it already spent plus the next
+    # kind's estimate stays under this. Kind by kind, 16 pod-security-
+    # policy kinds over 150k Pods each read as 1.5 s and together
+    # interpreted for ~9 minutes on a one-chip v5e host. The benchmark's
+    # PSP inventory generator refuses a program without this budget.
+    AUDIT_HOST_BUDGET_S = 30.0
 
     def _mesh_shardable(self, n_reviews: int) -> bool:
         """Mesh path gate: enough rows, and the power-of-two extraction
@@ -1931,14 +1958,17 @@ class TpuDriver(RegoDriver):
         return mask, cand, cand_reviews, use_mesh, trees
 
     def _audit_dispatch(self, target, kind, ct, cons, reviews, lookup_ns,
-                        sig_cache):
+                        sig_cache, defer=False):
         """Phase 1 for one compiled kind: encode (_audit_encode), then
         ASYNC device dispatch of every slab — SPMD over the mesh's data
         axis when one is available and the sweep is large enough, else
         the single-device slab pipeline. A sweep shape that has never
         executed serves from the host path while a background thread
         warms the device program (XLA compile must not stall the
-        audit). Returns consume state, or None for the host path."""
+        audit), as long as the audit's host time stays tolerable
+        (_warm_gate); past that, ("cold",) when `defer`, else the
+        compile is waited out. Returns consume state, ("cold",), or
+        None for the host path."""
         try:
             faults.fire("eval.device", kind=kind)
             encoded = self._audit_encode(target, kind, ct, cons, reviews,
@@ -1968,10 +1998,15 @@ class TpuDriver(RegoDriver):
                     # waiting out the compile: at audit scale (e.g. 50M
                     # masked pairs) minutes of interpretation would be
                     # far worse than blocking ~10-90s once
-                    if not self._warm_gate(sig, kind, warm_run,
+                    gate = self._warm_gate(sig, kind, warm_run,
                                            int(mask.sum()),
                                            fingerprint=ct.fingerprint,
-                                           what=f"mesh={use_mesh}"):
+                                           what=f"mesh={use_mesh}",
+                                           spent_s=self._audit_host_s,
+                                           defer=defer)
+                    if gate is None:
+                        return ("cold",)
+                    if not gate:
                         return None  # host path serves this audit
                 handle = self._dispatch_guarded(sig, ct, feats, enc, table,
                                                 derived, len(cand_reviews),
@@ -1983,7 +2018,8 @@ class TpuDriver(RegoDriver):
                     if self.async_warm and not self._warm_gate(
                             sig, kind, warm_run, int(mask.sum()),
                             fingerprint=ct.fingerprint,
-                            what=f"mesh={use_mesh}"):
+                            what=f"mesh={use_mesh}",
+                            spent_s=self._audit_host_s):
                         return None
                     handle = self._dispatch_handle(
                         ct, feats, enc, table, derived, len(cand_reviews),
@@ -2504,6 +2540,7 @@ class TpuDriver(RegoDriver):
                         trace))
         # feed the cost model in its own units (masked pairs per second)
         el = _time.monotonic() - t0
+        self._audit_host_s += el
         if el > 0:
             self.note_busy(el)
         if trace is None and el > 0.005 and n_masked >= 256:
@@ -2917,20 +2954,31 @@ class TpuDriver(RegoDriver):
     SPARSE_BATCH_MIN = 4096
 
     def _warm_gate(self, sig, kind, run_fn, n_masked_pairs,
-                   fingerprint=None, what="") -> bool:
+                   fingerprint=None, what="", spent_s=None,
+                   defer=False) -> Optional[bool]:
         """Shared block-when-cheaper policy for a cold sweep shape:
         kick the background warm and return False (serve host) when the
         host alternative is tolerable, else wait the compile out and
         return whether the program is now warm. True = dispatch on the
-        device."""
+        device.
+
+        spent_s: for an audit, the host seconds it already spent on
+        earlier kinds; the host is then tolerable while that plus this
+        kind's estimate stays under AUDIT_HOST_BUDGET_S (a batch's own
+        estimate under ASYNC_WARM_MAX_HOST_S). defer: return None
+        instead of waiting, so the caller can start the audit's other
+        compiles before it waits on any."""
         with self._warm_lock:
             if sig in self._warm_done:
                 return True
         ev = self._spawn_warm(sig, kind, run_fn, fingerprint=fingerprint,
                               what=what)
-        if n_masked_pairs / self._host_pair_rate <= \
-                self.ASYNC_WARM_MAX_HOST_S:
+        est = n_masked_pairs / self._host_pair_rate
+        if (est <= self.ASYNC_WARM_MAX_HOST_S if spent_s is None
+                else spent_s + est <= self.AUDIT_HOST_BUDGET_S):
             return False
+        if defer:
+            return None
         if ev is not None:
             ev.wait(timeout=600)
         with self._warm_lock:
@@ -3190,6 +3238,7 @@ class TpuDriver(RegoDriver):
         carry one-off compiles and must not steer admission batches to
         the host."""
         self._lat_sampled = True
+        self._audit_host_s = 0.0
         lookup_ns = self._namespace_lookup(target)
         inventory = self._inventory_tree(target)
         reviews = self._inventory_reviews(target)

@@ -931,8 +931,13 @@ def test_queue_depth_probe_and_stream_pending_gauge():
         gm.unregister_saturation_probe("test-bad")
     # stream backlog gauge (satellite: was logs-only)
     gm.report_stream_pending(42)
-    assert ('gatekeeper_tpu_audit_stream_pending_events 42'
-            in gm.REGISTRY.render())
+    try:
+        assert ('gatekeeper_tpu_audit_stream_pending_events 42'
+                in gm.REGISTRY.render())
+    finally:
+        # a drained backlog, as an audit stream leaves it: a later test
+        # in this process checks no gauge outlives its teardown
+        gm.report_stream_pending(0)
 
 
 def test_build_info_gauge():
