@@ -710,6 +710,7 @@ COMPILE_OUTCOMES = ("ok", "error")
 AUDIT_SWEEP_PATHS = ("incremental", "full_resync", "full", "stream")
 MATERIALIZE_PATHS = ("vectorized", "exact", "capped")
 JOIN_RENDER_SCOPES = ("holders", "inventory")
+SWEEP_LAYOUTS = ("rows", "constraints")
 CACHE_OUTCOMES = ("hit", "miss")
 STREAM_FLUSH_OUTCOMES = ("ok", "error", "skipped")
 PREVIEW_OUTCOMES = ("ok", "error", "invalid")
@@ -1184,6 +1185,17 @@ def report_join_render(scope: str, n: int) -> None:
         REGISTRY.counter_add("gatekeeper_tpu_audit_join_render_total",
                              "Rendered firing join pairs by inventory scope",
                              float(n), scope=scope)
+
+
+def report_sweep_layout(layout: str) -> None:
+    """One template's clause program traced for the device, by the
+    layout its shapes chose (ir/evaljax.py _sweep_layout): "rows" (rows
+    on the lanes, [C, axes..., N]) or "constraints" ([N, axes..., C])."""
+    if layout not in SWEEP_LAYOUTS:
+        layout = LABEL_FOLD
+    REGISTRY.counter_add("gatekeeper_tpu_sweep_layout_total",
+                         "Clause programs traced for the device, by the "
+                         "tensor layout their shapes chose", layout=layout)
 
 
 def report_msg_template_cache(outcome: str) -> None:

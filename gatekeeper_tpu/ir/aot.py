@@ -416,8 +416,10 @@ class AotStore:
     # ------------------------------------------------------ executables
 
     # bumped when what a stored executable holds changes for the same
-    # key (2: modules named jit_<tag>), so older entries are not served
-    KEY_VERSION = 2
+    # key (2: modules named jit_<tag>; 3: clause tensors laid out by
+    # evaljax._sweep_layout, pairs decoded without a scatter), so older
+    # entries are not served
+    KEY_VERSION = 3
 
     def entry_key(self, fingerprint: str, tag: str, static: tuple,
                   asig: tuple) -> str:

@@ -185,22 +185,42 @@ def _collect_axes(e: Expr, out: set) -> None:
         out.add(e.axis)
 
 
+def _sweep_layout(n: int, c: int) -> str:
+    """The clause tensors' layout for a traced sweep of n rows under c
+    constraints: the larger of the two takes the minor-most dim (the 128
+    TPU lanes; ties keep C there). "rows" lays a clause out as
+    [C, axes..., N], "constraints" as [N, axes..., C]."""
+    return "rows" if n > c else "constraints"
+
+
 class _ClausePlan:
-    """Static layout for one clause: [N, ax0, ax1, ..., C].
+    """Static layout for one clause: [N, ax0, ax1, ..., C] when `layout`
+    is "constraints", [C, ax0, ax1, ..., N] when it is "rows".
 
-    C (constraints, typically hundreds) sits in the minor-most dim so TPU
-    (8,128) tiling pads it by <3%; small iteration axes live in the middle
-    where padding is cheap. Putting axes minor-most instead costs up to
-    32x in both memory and VPU lanes."""
+    TPU tiles the two minor-most dims (8, 128): the minor-most maps to
+    the 128 lanes, so whichever of N and C is larger sits there
+    (_sweep_layout) and the small iteration axes live in the middle,
+    where padding is cheap. An audit chunk has thousands of rows and a
+    template usually one or a few constraints: with C = 1 on the lanes a
+    [..., a, 1] tile fills `a` of a vreg's 1,024 slots. An admission
+    batch of tens of rows under hundreds of constraints keeps C there.
+    XLA's layout assignment already moves rows minor-most in most of a
+    C = 1 sweep; laying them there in the program still took 4-17% off
+    a PSP kind's sweep on a v5e. Putting axes minor-most instead costs
+    up to 32x in both memory and VPU lanes."""
 
-    def __init__(self, program: Program, clause):
+    def __init__(self, program: Program, clause, layout: str):
         axes: set = set(a.name for a in clause.axes)
         for g in clause.guards:
             _collect_axes(g.expr, axes)
         self.axis_order = sorted(axes)
         self.axpos = {a: 1 + i for i, a in enumerate(self.axis_order)}
         self.rank = 2 + len(self.axis_order)
-        self.cpos = self.rank - 1
+        self.layout = layout
+        if layout == "rows":
+            self.cpos, self.npos = 0, self.rank - 1
+        else:
+            self.npos, self.cpos = 0, self.rank - 1
         self.clause = clause
         self.program = program
         self.axis_table = program.axis_table()
@@ -218,34 +238,39 @@ class _ClausePlan:
             seg_axes = [leaf_axis]
         return seg_axes
 
-    def place_obj(self, arr, slot: int, leaf_axis) -> Any:
-        """arr [N, K...] -> broadcastable [N, ...dims..., 1]."""
-        seg_axes = self._slot_axes(slot, False, leaf_axis)
-        shape = [arr.shape[0]] + [1] * (self.rank - 1)
-        src_dims = list(arr.shape[1:])
-        for ax, k in zip(seg_axes, src_dims):
+    def _place(self, arr, lead_pos: int, seg_axes: list) -> Any:
+        """arr [L, K...] -> the clause rank: L at lead_pos, each K at
+        its iteration axis' position, size 1 elsewhere."""
+        pos_list = [lead_pos]
+        for ax in seg_axes[:arr.ndim - 1]:
             pos = self.axpos.get(ax)
             if pos is None:
                 raise EvalError(f"axis {ax} not in clause layout")
+            pos_list.append(pos)
+        shape = [1] * self.rank
+        for pos, k in zip(pos_list, arr.shape):
             shape[pos] = k
-        # arr dims are already in seg order == sorted insertion order is NOT
-        # guaranteed; reshape works only if target positions are ascending
-        pos_list = [self.axpos[a] for a in seg_axes]
-        if pos_list != sorted(pos_list):
-            order = np.argsort(pos_list)
-            arr = jnp.transpose(arr, axes=[0] + [1 + int(i) for i in order])
+        # the target positions must ascend for a plain reshape: N or C
+        # leads the source, and seg order need not be sorted axis order
+        order = [int(i) for i in np.argsort(pos_list)]
+        if order != list(range(len(order))):
+            arr = jnp.transpose(arr, axes=order)
         return jnp.reshape(arr, shape)
 
+    def place_obj(self, arr, slot: int, leaf_axis) -> Any:
+        """arr [N, K...] -> broadcastable, N at npos."""
+        return self._place(arr, self.npos,
+                           self._slot_axes(slot, False, leaf_axis))
+
     def place_param(self, arr, slot: int, leaf_axis) -> Any:
-        """arr [C] or [C, P] -> [1, ...dims..., C]."""
-        shape = [1] * (self.rank - 1) + [arr.shape[0]]
+        """arr [C] or [C, P] -> broadcastable, C at cpos."""
+        seg_axes = []
         if arr.ndim == 2:
             seg_axes = self._slot_axes(slot, True, leaf_axis)
             if not seg_axes:
                 raise EvalError("param array has P dim but no axis")
-            shape[self.axpos[seg_axes[-1]]] = arr.shape[1]
-            arr = jnp.moveaxis(arr, 0, -1)  # [P, C]
-        return jnp.reshape(arr, shape)
+            seg_axes = seg_axes[-1:]
+        return self._place(arr, self.cpos, seg_axes)
 
     def presence(self, axis: str, feats: dict, params: dict) -> Any:
         ax = self.axis_table[axis]
@@ -545,7 +570,9 @@ def _eval_bool(plan: _ClausePlan, e: Expr, feats, params, table, derived) -> BPa
     raise EvalError(f"unsupported expr {type(e).__name__}")
 
 
-def _eval_clause(plan: _ClausePlan, feats, params, table, derived):
+def _eval_clause(plan: _ClausePlan, feats, params, table, derived,
+                 n: int, c: int):
+    """-> bool [n, c], whatever the plan's layout."""
     pair = None
     for g in plan.clause.guards:
         v = _eval_bool(plan, g.expr, feats, params, table, derived)
@@ -559,24 +586,27 @@ def _eval_clause(plan: _ClausePlan, feats, params, table, derived):
     for a in plan.clause.axes:
         success = jnp.logical_and(success,
                                   plan.presence(a.name, feats, params))
-    # broadcast to full rank before reducing (success may be size-1 dims)
-    n = 1
-    c = 1
-    for slot_arrs in feats.values():
-        for arr in slot_arrs.values():
-            n = max(n, arr.shape[0])
-    for slot_arrs in params.values():
-        for arr in slot_arrs.values():
-            c = max(c, arr.shape[0])
     # reduce FIRST, broadcast last: materializing the full-rank success
     # tensor would carry tiny minor dims that TPU layouts pad to (8,128)
     # tiles — reducing lets XLA fuse the whole clause into the reduction.
-    # layout is [N, axes..., C]; reduce the middle dims.
+    # The iteration axes are the middle dims in either layout.
     if success.ndim > 2:
         success = jnp.any(success, axis=tuple(range(1, success.ndim - 1)))
-    if success.ndim == 1:
-        success = success[None, :]
+    if plan.layout == "rows":
+        # [C, N] is small next to the clause: transposed here, the bit
+        # pack and pair decode downstream see [n, c] on every path
+        return jnp.broadcast_to(success, (c, n)).T
     return jnp.broadcast_to(success, (n, c))
+
+
+def _sweep_shape(feats: dict, params: dict) -> tuple[int, int]:
+    """(rows, constraints) of a traced sweep: the leading dims of the
+    feature and parameter leaves (1 where there are none)."""
+    n = max((a.shape[0] for arrs in feats.values() for a in arrs.values()),
+            default=1)
+    c = max((a.shape[0] for arrs in params.values()
+             for a in arrs.values()), default=1)
+    return n, c
 
 
 def _param_c(params: dict) -> int:
@@ -678,28 +708,41 @@ def _pair_expand(packed, valid_rows, row0, c, pcap):
     """Shared device tail for every pair kernel: masked bit-packed
     verdicts [R, W] -> one [pcap+1, 2] uint32 block — header row
     carrying the true pair count, then (global row, constraint) index
-    pairs in row-major order (fixed-capacity nonzero over the unpacked
-    bit matrix; jnp.nonzero's ascending flat order IS row-major).
-    `valid_rows` masks extraction padding / slab overlap; `row0` is the
-    block's global row offset; `c` (traced) masks the C-bucket padding
-    columns so library edits inside a bucket still hit this program."""
+    pairs in row-major order. `valid_rows` masks extraction padding /
+    slab overlap; `row0` is the block's global row offset; `c` (traced)
+    masks the C-bucket padding columns so library edits inside a bucket
+    still hit this program.
+
+    No scatter: a fixed-capacity nonzero over the unpacked [R, W*32]
+    bit matrix (jnp.nonzero's bincount) is a scatter-add of R*W*32
+    updates into pcap bins, which the TPU serializes: ~25 ms per
+    81,920-row slab on a v5e, whatever the clause. Instead each row's
+    popcount is prefix-summed, pair k's row is a binary search of k in
+    those sums, and its column the (rank+1)-th set bit of that row's
+    words."""
     r, w = packed.shape
-    w32 = w * 32
-    packed = jnp.where(valid_rows[:, None], packed, jnp.uint32(0))
-    bits = (packed[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
+    nbits = jnp.clip(c - 32 * jnp.arange(w, dtype=jnp.int32), 0, 32)
+    wmask = jnp.where(nbits >= 32, jnp.uint32(0xFFFFFFFF),
+                      (jnp.uint32(1) << nbits.astype(jnp.uint32))
+                      - jnp.uint32(1))
+    packed = jnp.where(valid_rows[:, None], packed & wmask[None, :],
+                       jnp.uint32(0))
+    per_row = jnp.sum(jax.lax.population_count(packed), axis=1,
+                      dtype=jnp.int32)
+    ends = jnp.cumsum(per_row)  # pairs in rows [0, i]
+    pcount = ends[-1]
+    k = jnp.arange(pcap, dtype=jnp.int32)
+    ok = k < pcount
+    lrow = jnp.minimum(jnp.searchsorted(ends, k, side="right",
+                                        method="scan_unrolled"), r - 1)
+    rank = k - (ends[lrow] - per_row[lrow])  # pair k's place in its row
+    bits = (packed[lrow][:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
             ) & jnp.uint32(1)
-    flat = bits.reshape(r, w32).astype(bool)
-    flat = jnp.logical_and(flat, jnp.arange(w32, dtype=jnp.int32)[None, :]
-                           < c)
-    pcount = jnp.sum(flat, dtype=jnp.int32)
-    pidx = jnp.nonzero(flat.reshape(-1), size=pcap,
-                       fill_value=r * w32)[0]
-    ok = pidx < r * w32
-    sel = jnp.where(ok, pidx, 0)
-    prow = (row0 + sel // w32).astype(jnp.uint32)
-    pcol = (sel % w32).astype(jnp.uint32)
-    prow = jnp.where(ok, prow, jnp.uint32(0))
-    pcol = jnp.where(ok, pcol, jnp.uint32(0))
+    seen = jnp.cumsum(bits.reshape(pcap, w * 32).astype(jnp.int32),
+                      axis=1)
+    pcol = jnp.sum(seen <= rank[:, None], axis=1, dtype=jnp.int32)
+    prow = jnp.where(ok, row0 + lrow, 0).astype(jnp.uint32)
+    pcol = jnp.where(ok, pcol, 0).astype(jnp.uint32)
     body = jnp.stack([prow, pcol], axis=1)  # [pcap, 2]
     header = jnp.zeros((1, 2), jnp.uint32)
     header = header.at[0, 0].set(pcount.astype(jnp.uint32))
@@ -843,8 +886,11 @@ class CompiledTemplate:
         self.table = table
         self.match = match
         self.program = resolve_consts(program, table, match)
-        self.plans = [_ClausePlan(self.program, c)
-                      for c in self.program.clauses]
+        # one plan per clause and layout; _eval picks the layout from
+        # the traced shapes (_sweep_layout)
+        self.plans = {layout: [_ClausePlan(self.program, c, layout)
+                               for c in self.program.clauses]
+                      for layout in ("rows", "constraints")}
         # AOT program store (ir/aot.py): every jit below is wrapped so
         # compiled executables persist across processes and a warm boot
         # deserializes instead of recompiling. The fingerprint is over
@@ -913,9 +959,16 @@ class CompiledTemplate:
         return loaded
 
     def _eval(self, feats, params, table, derived):
+        """-> bool [n, c]; runs at trace time, once per compiled
+        program."""
+        from ..control.metrics import report_sweep_layout
+
+        n, c = _sweep_shape(feats, params)
+        layout = _sweep_layout(n, c)
+        report_sweep_layout(layout)
         out = None
-        for plan in self.plans:
-            v = _eval_clause(plan, feats, params, table, derived)
+        for plan in self.plans[layout]:
+            v = _eval_clause(plan, feats, params, table, derived, n, c)
             out = v if out is None else jnp.logical_or(out, v)
         return out
 

@@ -4,7 +4,11 @@ A compiled template is a `Program`: extraction slots describing what to
 pull out of each review object, parameter slots describing what to encode
 per constraint, and clauses — tri-state boolean expressions over the
 implicit axes N (objects) × C (constraints) plus small iteration axes
-(container lists, label maps, parameter lists).
+(container lists, label maps, parameter lists). Neither N nor C is
+assumed large: an audit sweeps thousands of rows per chunk under, most
+often, one constraint per template, and an admission batch tens of rows
+under up to hundreds; the device picks the tensor layout per traced
+sweep from the two (evaljax._sweep_layout).
 
 The device program answers ONE question per (object, constraint) pair:
 "does at least one violation clause fire?" — the 99.99%-reject filter of
