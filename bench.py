@@ -157,8 +157,8 @@ def main() -> int:
     feat_key = (driver._data_gen, hash(cand.tobytes()))
     cand_reviews = [reviews[int(i)] for i in cand]
     t0 = time.time()
-    slabs = list(driver.eval_compiled_pairs_slabbed(
-        ct, "K8sRequiredLabels", cand_reviews, cons, feat_key=feat_key))
+    slabs = [driver.eval_compiled_pairs(
+        ct, "K8sRequiredLabels", cand_reviews, cons, feat_key=feat_key)]
     sweep_s = time.time() - t0  # device sweep WITHOUT overlap; the
     # audit overlaps slab k+1 with slab k's materialization
     inventory = driver._inventory_tree(TARGET)

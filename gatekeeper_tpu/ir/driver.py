@@ -43,7 +43,8 @@ from ..rego import ast as A
 from ..utils import faults, profiling
 from ..target.batch import match_masks
 from .compile import Uncompilable, compile_template
-from .evaljax import CompiledTemplate, EvalError, _param_c
+from .evaljax import (CompiledTemplate, EvalError, _param_c, _rows,
+                      _slab_plan)
 from .features import extract_batch
 from .params import encode_params
 
@@ -434,17 +435,9 @@ class TpuDriver(RegoDriver):
 
         self.async_warm = _os.environ.get(
             "GATEKEEPER_TPU_ASYNC_COMPILE", "1") != "0"
-        # mesh path tuning: the review-count floor below which a sweep
-        # stays single-device, and an optional LOCAL slab-size override
-        # for the double-buffered mesh slab loop (None = auto-sized in
-        # fires_pairs_mesh_dispatch)
+        # the review-count floor below which a sweep stays single-device
         self.MESH_MIN_REVIEWS = int(_os.environ.get(
             "GATEKEEPER_TPU_MESH_MIN_REVIEWS", self.MESH_MIN_REVIEWS))
-        slab_env = _os.environ.get("GATEKEEPER_TPU_MESH_SLAB", "")
-        self.mesh_slab_local: Optional[int] = \
-            int(slab_env) if slab_env else None
-        self.sweep_chunk = int(_os.environ.get(
-            "GATEKEEPER_TPU_SWEEP_CHUNK", "8192"))
         self._warm_done: set = set()
         self._warm_inflight: dict = {}           # sig -> done Event
         # sigs adopted from the AOT store's manifest (not yet executed
@@ -1781,18 +1774,13 @@ class TpuDriver(RegoDriver):
 
         return _bucket(n_reviews) % self._mesh.shape["data"] == 0
 
-    @staticmethod
-    def _sweep_slab(n_true: int, chunk: int = 8192) -> int:
-        half = (n_true + 1) // 2
-        return max(chunk * 4, ((half + chunk - 1) // chunk) * chunk)
-
     def _sweep_sig(self, kind, feats, enc, table, derived, n_true,
                    use_mesh) -> tuple:
         """Shape signature of one sweep's jit cache keys: a device
         program is "warm" once these exact shapes executed. The slab
-        (derived from n_true) is a STATIC jit key on the single-device
-        path — two sweeps in the same feature bucket but different
-        slabs compile different programs."""
+        (evaljax._slab_plan, from n_true on one device) is a STATIC jit
+        key — two sweeps in the same feature bucket but different slabs
+        compile different programs."""
         def shapes(tree):
             out = []
             if isinstance(tree, dict):
@@ -1800,7 +1788,8 @@ class TpuDriver(RegoDriver):
                     out.append((k, shapes(tree[k])))
                 return tuple(out)
             return tuple(getattr(tree, "shape", ()))
-        slab = 0 if use_mesh else self._sweep_slab(n_true)
+        shards = self._mesh.shape["data"] if use_mesh else 1
+        slab = _slab_plan(_rows(feats), n_true, shards)[1]
         return (kind, use_mesh, slab, shapes(feats), shapes(enc),
                 tuple(getattr(table, "shape", ())), shapes(derived))
 
@@ -1819,10 +1808,8 @@ class TpuDriver(RegoDriver):
         store (never executed in THIS process) the dispatch runs under
         the no-inline-compile guard: a store miss returns None (caller
         re-gates the sig as cold) instead of stalling the serving
-        thread on XLA. A one-chunk sweep, which would defer its jit call
-        to consume time, runs inside the guard too."""
+        thread on XLA."""
         from . import aot as aot_mod
-        from .evaljax import _EagerPairs
 
         with self._warm_lock:
             restored = sig in self._warm_restored
@@ -1834,8 +1821,6 @@ class TpuDriver(RegoDriver):
                 h = self._dispatch_handle(ct, feats, enc, table,
                                           derived, n_true, use_mesh,
                                           n_cons=n_cons)
-                if isinstance(h, _EagerPairs):
-                    h.force()
         except aot_mod.WouldCompile:
             log.info("adopted sweep signature for %s not backed by a "
                      "stored executable after all; re-warming it off "
@@ -1847,16 +1832,12 @@ class TpuDriver(RegoDriver):
         return h
 
     def _dispatch_handle(self, ct, feats, enc, table, derived, n_true,
-                         use_mesh, chunk=None, n_cons=None):
-        chunk = chunk or self.sweep_chunk
-        if use_mesh:
-            return ct.fires_pairs_mesh_dispatch(
-                feats, enc, table, self._mesh, derived, chunk=chunk,
-                n_true=n_true, slab=self.mesh_slab_local, n_cons=n_cons)
-        return ct.fires_pairs_dispatch(feats, enc, table, derived,
-                                       chunk=chunk,
-                                       slab=self._sweep_slab(n_true, chunk),
-                                       n_true=n_true, n_cons=n_cons)
+                         use_mesh, n_cons=None):
+        """Dispatch one sweep's firing-pair extraction (async): the one
+        way the driver asks the device for pairs."""
+        return ct.fires_pairs_dispatch(
+            feats, enc, table, derived, n_true=n_true, n_cons=n_cons,
+            mesh=self._mesh if use_mesh else None)
 
     def _spawn_warm(self, sig, kind, run_fn, fingerprint=None,
                     what=""):
@@ -2199,26 +2180,20 @@ class TpuDriver(RegoDriver):
         out: list[Result] = []
         first_sync = True
         t_busy0 = _time.monotonic()
-        # mesh handles label blocks with their data-shard index: the
-        # per-shard materialize histograms ride that, and — since the
-        # SLAB loop's blocks are not globally row-major — results are
-        # reassembled by each block's first global row (disjoint
-        # contiguous ranges per block, sorted within)
-        labeled = getattr(handle, "pairs_labeled", None)
+        # mesh blocks carry their data-shard index: the per-shard
+        # materialize histograms ride that, and — since a mesh sweep's
+        # blocks are not globally row-major — results are reassembled by
+        # each block's first global row (disjoint contiguous ranges per
+        # block, sorted within)
         blocks: list = []
         try:
-            it = iter(labeled()) if labeled is not None \
-                else iter(handle.pairs())
+            it = handle.pairs()
             while True:
                 with timers.phase("device_sweep", kind=kind):
                     item = next(it, None)
                 if item is None:
                     break
-                shard = None
-                if labeled is not None:
-                    shard, rows, cols = item
-                else:
-                    rows, cols = item
+                shard, rows, cols = item
                 if first_sync:
                     # DISPATCH->first-result latency, sampled only for
                     # the audit's first consumed kind (later kinds'
@@ -2656,38 +2631,19 @@ class TpuDriver(RegoDriver):
                             feat_key=None, cand=None,
                             target=None) -> tuple:
         """(rows, cols) firing pairs, row-major — the sparse form of
-        eval_compiled (audits are ~99% rejects; see fires_pairs)."""
+        eval_compiled (audits are ~99% rejects): one device sweep
+        (_dispatch_handle), its blocks concatenated."""
         feats, enc, table, derived = self._prepare_eval(ct, kind, reviews,
                                                         cons, feat_key,
                                                         cand=cand,
                                                         target=target)
-        rows, cols = ct.fires_pairs(feats, enc, table, derived,
-                                    n_true=len(reviews),
-                                    n_cons=len(cons))
+        none = np.zeros(0, dtype=np.int64)
+        blocks = list(self._dispatch_handle(
+            ct, feats, enc, table, derived, len(reviews), False,
+            n_cons=len(cons)).pairs()) or [(None, none, none)]
+        rows = np.concatenate([b[1] for b in blocks])
+        cols = np.concatenate([b[2] for b in blocks])
         return _expand_parameterless(rows, cols, _param_c(enc), len(cons))
-
-    def eval_compiled_pairs_slabbed(self, ct: CompiledTemplate, kind: str,
-                                    reviews: list[dict], cons: list[dict],
-                                    feat_key=None, cand=None, target=None):
-        """Iterator form of eval_compiled_pairs over N-axis slabs, with
-        every slab's device work dispatched before the first yield (see
-        CompiledTemplate.fires_pairs_slabbed) — the audit's
-        sweep/materialize pipeline."""
-        feats, enc, table, derived = self._prepare_eval(ct, kind, reviews,
-                                                        cons, feat_key,
-                                                        cand=cand,
-                                                        target=target)
-        c_dev = _param_c(enc)
-        # two slabs: the second sweep overlaps the first slab's host
-        # materialization; every further slab adds a fetch roundtrip
-        chunk = 8192
-        half = (len(reviews) + 1) // 2
-        slab = max(chunk * 4, ((half + chunk - 1) // chunk) * chunk)
-        for rows, cols in ct.fires_pairs_slabbed(feats, enc, table, derived,
-                                                 chunk=chunk, slab=slab,
-                                                 n_true=len(reviews),
-                                                 n_cons=len(cons)):
-            yield _expand_parameterless(rows, cols, c_dev, len(cons))
 
     def _prepare_eval(self, ct: CompiledTemplate, kind: str,
                       reviews: list[dict], cons: list[dict], feat_key,
@@ -3032,7 +2988,7 @@ class TpuDriver(RegoDriver):
         c_dev = _param_c(enc)
         pairs = []
         first = True
-        for rows, cols in handle.pairs():
+        for _shard, rows, cols in handle.pairs():
             if first:
                 self._observe("_dev_batch_lat_s", _time.monotonic() - t0)
                 first = False

@@ -618,82 +618,73 @@ def _param_c(params: dict) -> int:
     return 1
 
 
-class _EagerPairs:
-    """Dispatch handle for workloads below the slab threshold: the
-    monolithic packed sweep + row gather dispatch at CONSTRUCTION (so a
-    multi-template audit overlaps every kind's device work); only the
-    dense-small / parameter-only paths stay lazy (they are
-    latency-trivial)."""
-
-    def __init__(self, ct, feats, params, table, derived, chunk, n_true,
-                 n_cons=None):
-        self._ct = ct
-        self._args = (feats, params, table, derived, chunk, n_true,
-                      n_cons)
-        self._st = None
-        self._out = None
-        if feats:
-            n_feat = next(iter(next(iter(
-                feats.values())).values())).shape[0]
-            n = n_feat if n_true is None else min(n_feat, n_true)
-            if n_feat > chunk:
-                c = _param_c(params)
-                if n_cons is not None:
-                    c = min(c, n_cons)
-                self._st = ct._pairs_dispatch_mono(
-                    feats, params, table, derived, chunk, n, c)
-
-    def force(self) -> None:
-        """Run a lazy sweep now: its jit call (and any compile) then
-        happens in the caller's scope, e.g. a no_inline_compile guard,
-        not at consume time."""
-        if self._st is None and self._out is None:
-            feats, params, table, derived, chunk, n_true, n_cons = \
-                self._args
-            self._out = self._ct.fires_pairs(feats, params, table,
-                                             derived, chunk=chunk,
-                                             n_true=n_true, n_cons=n_cons)
-
-    def pairs(self):
-        if self._st is not None:
-            yield self._ct._pairs_consume_mono(self._st)
-            return
-        self.force()
-        yield self._out
+# rows per lax.map step of a sweep: bounds the [chunk, axes..., C]
+# clause intermediates
+CHUNK = 8192
+# a mesh shard of at least this many chunks sweeps in this many slabs
+MESH_SLABS = 8
+# a slab of one chunk gets room for every (row, constraint) pair its
+# verdicts can hold while those pairs times its verdict words per row
+# (the pair decode's [pcap, 32 W] bit matrix) stay within this: it then
+# never overflows, so more firing pairs (a library edit, inventory
+# churn) cost no second program, as the dense verdicts such a sweep
+# returned before did not. The decode's device time grows with its
+# capacity: ~2.8 ms at 16,384 pairs, ~9 ms at 65,536 on a v5e
+ALL_PAIRS = 1 << 15
 
 
-class _SlabPairs:
-    """Pending slab kernels; .pairs() syncs in dispatch order with the
-    capacity-retry loop."""
+def _rows(feats: dict) -> int:
+    """Leading (row) dim of a feature tree; 0 for a program that reads
+    no object field."""
+    for arrs in feats.values():
+        for a in arrs.values():
+            return a.shape[0]
+    return 0
 
-    def __init__(self, ct, pend, feats, params, table, derived, chunk,
-                 slab, n, c):
-        self._ct = ct
-        self._pend = pend
-        self._args = (feats, params, table, derived, chunk, slab, n, c)
 
-    def pairs(self):
-        ct = self._ct
-        feats, params, table, derived, chunk, slab, n, c = self._args
-        for k, (used_pcap, dev_arr) in enumerate(self._pend):
-            arr = np.asarray(dev_arr)  # sync point + single fetch
-            pcount = int(arr[0, 0])
-            while pcount > used_pcap:
-                used_pcap = max(used_pcap,
-                                1 << (pcount - 1).bit_length())
-                fn2 = ct._slab_pairs_jit(chunk, slab, used_pcap)
-                arr = np.asarray(fn2(feats, params, table, derived,
-                                     np.int32(k * slab), np.int32(n),
-                                     np.int32(c)))
-                pcount = int(arr[0, 0])
-            ct._rows_cap = max(ct._rows_cap,
-                               (1 << (pcount - 1).bit_length())
-                               if pcount > 1 else 256)
-            yield _decode_pair_blocks(arr, pcount)
+def _pad_rows(feats: dict, rows: int) -> dict:
+    """Zero-pad every feature leaf's row axis to `rows`."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.pad(a, [(0, rows - a.shape[0])]
+                          + [(0, 0)] * (a.ndim - 1)), feats)
+
+
+def _slab_plan(n_feat: int, n_true: int, n_shards: int = 1
+               ) -> tuple[int, int]:
+    """(chunk, slab) of a sweep over n_feat feature rows, n_true of them
+    real, split over n_shards devices (the slab then counts one shard's
+    rows). The one place a sweep's slab is decided.
+
+    One device: up to four chunks of true rows sweep as one slab of
+    every padded row; above that, two slabs, so the second sweeps under
+    the host materialization of the first (each further slab adds a
+    fetch round-trip). A mesh shard of at least MESH_SLABS chunks sweeps
+    in MESH_SLABS slabs, two in flight, so the host materializes one
+    while the mesh sweeps the next; a smaller shard in one."""
+    n = n_feat // n_shards
+    if n <= CHUNK:
+        return n, n
+    if n_shards > 1:
+        return CHUNK, (n // MESH_SLABS if n >= MESH_SLABS * CHUNK else n)
+    if n_true <= 4 * CHUNK:
+        return CHUNK, -(-n // CHUNK) * CHUNK
+    half = -(-n_true // 2)
+    return CHUNK, max(4 * CHUNK, -(-half // CHUNK) * CHUNK)
+
+
+def _pack_chunk(fires):
+    """bool [R, C] -> uint32 [R, W]: bit j of word w is column 32w + j."""
+    r, c = fires.shape
+    w = (c + 31) // 32
+    if w * 32 > c:
+        fires = jnp.pad(fires, ((0, 0), (0, w * 32 - c)))
+    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+    return jnp.sum(jnp.where(fires.reshape(r, w, 32), weights,
+                             jnp.uint32(0)), axis=-1, dtype=jnp.uint32)
 
 
 def _decode_pair_blocks(arr: np.ndarray, pcount: int):
-    """(rows, cols) from one device pair block: the kernels decode the
+    """(rows, cols) from one device pair block: the sweep decodes the
     bit-packed verdicts to dense (row, constraint) index pairs ON
     DEVICE (row-major, invalid columns already masked), so the host
     does no bitmask unpacking at all — two int64 casts and a slice."""
@@ -705,13 +696,12 @@ def _decode_pair_blocks(arr: np.ndarray, pcount: int):
 
 
 def _pair_expand(packed, valid_rows, row0, c, pcap):
-    """Shared device tail for every pair kernel: masked bit-packed
-    verdicts [R, W] -> one [pcap+1, 2] uint32 block — header row
-    carrying the true pair count, then (global row, constraint) index
-    pairs in row-major order. `valid_rows` masks extraction padding /
-    slab overlap; `row0` is the block's global row offset; `c` (traced)
-    masks the C-bucket padding columns so library edits inside a bucket
-    still hit this program.
+    """The sweep's device tail: masked bit-packed verdicts [R, W] -> one
+    [pcap+1, 2] uint32 block — header row carrying the true pair count,
+    then (global row, constraint) index pairs in row-major order.
+    `valid_rows` masks extraction padding / slab overlap; `row0` is the
+    block's global row offset; `c` (traced) masks the C-bucket padding
+    columns so library edits inside a bucket still hit this program.
 
     No scatter: a fixed-capacity nonzero over the unpacked [R, W*32]
     bit matrix (jnp.nonzero's bincount) is a scatter-add of R*W*32
@@ -749,133 +739,72 @@ def _pair_expand(packed, valid_rows, row0, c, pcap):
     return jnp.concatenate([header, body], axis=0)
 
 
-class _MeshPairs:
-    """Pending mesh sweep; .pairs() syncs the single all-shard fetch and
-    decodes shard row blocks in mesh order (global row-major, since the
-    data axis shards the N axis into contiguous ordered blocks)."""
+class _PairSweep:
+    """A dispatched sweep's firing pairs, slab by slab.
 
-    def __init__(self, ct, mesh, dev, rcap, chunk, args):
-        self._ct = ct
-        self._mesh = mesh
-        self._dev = dev
-        self._rcap = rcap
-        self._chunk = chunk
-        self._args = args  # (feats, params, table, derived, n_valid, c)
+    The first WINDOW slabs dispatch at construction (async), so an audit
+    dispatches every kind's sweep before it consumes any; .pairs() then
+    refills the window before each slab's fetch, so the host
+    materializes slab k while the device sweeps slab k+1. A slab of one
+    chunk small enough (ALL_PAIRS) has room for every pair it can hold.
+    Otherwise a block whose pairs overflow their capacity re-runs its
+    slab at the next power of two, and the template keeps the grown
+    capacity (one for one device, one for a mesh), so steady-state
+    sweeps fetch once per slab."""
+
+    WINDOW = 2
+
+    def __init__(self, ct, mesh, args, chunk, slab, n_slabs, done=()):
+        self._ct, self._mesh, self._args = ct, mesh, args
+        self._chunk, self._slab, self._n_slabs = chunk, slab, n_slabs
+        c_pad = _param_c(args[1]) if args else 1
+        most = slab * c_pad
+        self._all = most if chunk == slab and \
+            most * -(-c_pad // 32) <= ALL_PAIRS else 0
+        self._done = list(done)
+        self._pend: list = []
+        self._next = 0
+        while self._next < min(self.WINDOW, n_slabs):
+            self._dispatch()
+
+    def _run(self, s: int, pcap: int):
+        feats, params, table, derived, n_valid, c = self._args
+        fn = self._ct._sweep_jit(self._mesh, self._chunk, self._slab, pcap)
+        return fn(feats, params, table, derived, np.int32(s * self._slab),
+                  n_valid, c)
+
+    def _dispatch(self) -> None:
+        pcap = self._all or self._ct._pcap[self._mesh is not None]
+        self._pend.append((self._next, pcap, self._run(self._next, pcap)))
+        self._next += 1
 
     def pairs(self):
-        for _shard, rows, cols in self.pairs_labeled():
-            yield rows, cols
-
-    def pairs_labeled(self):
-        """(shard, rows, cols) per data shard — the shard index feeds
-        the per-shard audit stage histograms."""
+        """(shard, rows, cols) per block: firing (row, constraint) index
+        pairs, row-major within the block. On a mesh, shard is the data
+        shard's index and blocks come in (slab, shard) order, which is
+        not globally row-major: an order-sensitive consumer reassembles
+        them by first row. On one device shard is None and the blocks
+        are in row order."""
+        yield from self._done
         ct = self._ct
-        feats, params, table, derived, n_valid, c = self._args
-        rcap = self._rcap
-        arr = np.asarray(self._dev)  # sync point + single fetch
-        n_shards = arr.shape[0] // (rcap + 1)
-        counts = arr[:: rcap + 1, 0].astype(np.int64)
-        while counts.max(initial=0) > rcap:
-            # some shard overflowed its gather capacity: re-run the whole
-            # sweep at the next power of two (rare; remembered below)
-            rcap = max(rcap, 1 << (int(counts.max()) - 1).bit_length())
-            fn = ct._mesh_pairs_jit(self._mesh, self._chunk, rcap)
-            arr = np.asarray(fn(feats, params, table, derived, n_valid,
-                                np.int32(c)))
-            counts = arr[:: rcap + 1, 0].astype(np.int64)
-        # RATCHET, like _SlabPairs does for _rows_cap: resetting to this
-        # sweep's count made alternating small/large mesh sweeps re-trip
-        # the overflow re-run (and its jit recompile) on every grow
-        ct._rows_cap_mesh = max(ct._rows_cap_mesh, 256,
-                                (1 << (int(counts.max()) - 1).bit_length())
-                                if counts.max(initial=0) > 1 else 256)
-        for k in range(n_shards):
-            block = arr[k * (rcap + 1): (k + 1) * (rcap + 1)]
-            rows, cols = _decode_pair_blocks(block, int(block[0, 0]))
-            yield k, rows, cols
-
-
-class _MeshSlabPairs:
-    """Pending DOUBLE-BUFFERED mesh slab sweeps.
-
-    The monolithic mesh dispatch (_MeshPairs) syncs the whole sweep in
-    one fetch, so at 1M+ objects the host sits idle through the entire
-    device pass and the mesh sits idle through the entire
-    materialization tail. Slabbing the LOCAL row axis fixes both: each
-    slab is one SPMD dispatch covering every shard's next `lslab` local
-    rows, at most WINDOW slabs are in flight, and the only
-    jax.block_until_ready sits at the slab boundary — while the host
-    materializes slab k's firing pairs, the mesh is already sweeping
-    slab k+1. Yield order is (slab, shard): blocks are NOT globally
-    row-major (shard d's slab-s rows are d*n_loc + [s*lslab, ...)), so
-    order-sensitive consumers reassemble by each block's first global
-    row (the driver's audit consume does)."""
-
-    WINDOW = 2  # double-buffered: one slab syncing, one in flight
-
-    def __init__(self, ct, mesh, chunk, lslab, n_slabs, rcap, args):
-        self._ct = ct
-        self._mesh = mesh
-        self._chunk = chunk
-        self._lslab = lslab
-        self._n_slabs = n_slabs
-        # (feats, params, table, derived, n_valid, c)
-        self._args = args
-        fn = ct._mesh_slab_pairs_jit(mesh, chunk, lslab, rcap)
-        # prime the pipeline NOW (dispatch is async): the audit's
-        # cross-kind window consumes handles long after construction
-        self._pend = [
-            (s, rcap, fn(args[0], args[1], args[2], args[3],
-                         np.int32(s * lslab), args[4],
-                         np.int32(args[5])))
-            for s in range(min(self.WINDOW, n_slabs))]
-        self._next = len(self._pend)
-
-    def pairs(self):
-        for _shard, rows, cols in self.pairs_labeled():
-            yield rows, cols
-
-    def pairs_labeled(self):
-        ct = self._ct
-        feats, params, table, derived, n_valid, c = self._args
-        lslab = self._lslab
+        on_mesh = self._mesh is not None
         while self._pend:
-            s, rcap, dev = self._pend.pop(0)
+            s, pcap, dev = self._pend.pop(0)
             if self._next < self._n_slabs:
-                # keep the window full BEFORE blocking: the refill slab
-                # overlaps this slab's fetch + materialization
-                fn = ct._mesh_slab_pairs_jit(self._mesh, self._chunk,
-                                             lslab, ct._rows_cap_mesh)
-                self._pend.append(
-                    (self._next, ct._rows_cap_mesh,
-                     fn(feats, params, table, derived,
-                        np.int32(self._next * lslab), n_valid,
-                        np.int32(c))))
-                self._next += 1
-            jax.block_until_ready(dev)  # the slab boundary: the ONLY
-            # sync point in the loop
-            arr = np.asarray(dev)
-            n_shards = arr.shape[0] // (rcap + 1)
-            counts = arr[:: rcap + 1, 0].astype(np.int64)
-            while counts.max(initial=0) > rcap:
-                # a shard overflowed its gather capacity: re-run THIS
-                # slab at the next power of two (rare; ratcheted below)
-                rcap = max(rcap,
-                           1 << (int(counts.max()) - 1).bit_length())
-                fn = ct._mesh_slab_pairs_jit(self._mesh, self._chunk,
-                                             lslab, rcap)
-                arr = np.asarray(fn(feats, params, table, derived,
-                                    np.int32(s * lslab), n_valid,
-                                    np.int32(c)))
-                counts = arr[:: rcap + 1, 0].astype(np.int64)
-            ct._rows_cap_mesh = max(
-                ct._rows_cap_mesh, 256,
-                (1 << (int(counts.max()) - 1).bit_length())
-                if counts.max(initial=0) > 1 else 256)
-            for k in range(n_shards):
-                block = arr[k * (rcap + 1): (k + 1) * (rcap + 1)]
+                self._dispatch()
+            arr = np.asarray(dev)  # the slab boundary: sync + one fetch
+            top = int(arr[:: pcap + 1, 0].max())
+            while top > pcap:
+                pcap = 1 << (top - 1).bit_length()
+                arr = np.asarray(self._run(s, pcap))
+                top = int(arr[:: pcap + 1, 0].max())
+            if not self._all:
+                ct._pcap[on_mesh] = max(ct._pcap[on_mesh], 256,
+                                        1 << max(top - 1, 0).bit_length())
+            for k in range(arr.shape[0] // (pcap + 1)):
+                block = arr[k * (pcap + 1):(k + 1) * (pcap + 1)]
                 rows, cols = _decode_pair_blocks(block, int(block[0, 0]))
-                yield k, rows, cols
+                yield (k if on_mesh else None), rows, cols
 
 
 class CompiledTemplate:
@@ -903,11 +832,10 @@ class CompiledTemplate:
         self.fingerprint = program_fingerprint(self.program, kind)
         self._fn = self._ajit("eval", (), self._eval)
         self._scan_cache: dict[int, Any] = {}
-        self._pairs_cache: dict[tuple, Any] = {}
-        # remembered firing-pair gather capacity (see _gather_pairs)
-        self._rows_cap = 256
-        # per-shard capacity for the mesh sweep (fires_pairs_mesh_dispatch)
-        self._rows_cap_mesh = 256
+        self._sweep_cache: dict[tuple, Any] = {}
+        # pair capacity of a sweep block, by "on a mesh" (per shard
+        # there); grown by _PairSweep, never shrunk
+        self._pcap = {False: 256, True: 256}
 
     def _ajit(self, tag: str, static: tuple, fn):
         from .aot import AotJit
@@ -934,21 +862,14 @@ class CompiledTemplate:
                 elif tag == "scan":
                     w = self._scan_jit(*static)
                 elif tag == "slabp":
-                    w = self._slab_pairs_jit(*static)
-                elif tag == "pairsg":
-                    w = self._pairs_jit(*static)
-                elif tag in ("meshp", "mesh-slabp"):
+                    w = self._sweep_jit(None, *static)
+                elif tag == "mesh-slabp":
                     if mesh is None or \
                             tuple(sorted(mesh.shape.items())) != static[-1]:
                         continue
-                    if tag == "meshp":
-                        w = self._mesh_pairs_jit(mesh, *static[:-1])
-                    else:
-                        w = self._mesh_slab_pairs_jit(mesh, *static[:-1])
+                    w = self._sweep_jit(mesh, *static[:-1])
                 else:
-                    # pre-pair-decode tags ("slab"/"rows"/"mesh"/
-                    # "mesh-slab") produced row-bitmask blocks; their
-                    # stored executables are format-incompatible — skip
+                    # tags of retired programs: nothing here runs them
                     continue
                 key = self.aot.entry_key(self.fingerprint, tag, static,
                                          ent["asig"])
@@ -972,6 +893,16 @@ class CompiledTemplate:
             out = v if out is None else jnp.logical_or(out, v)
         return out
 
+    def _packed(self, feats, params, table, derived, chunk: int):
+        """Traced: bit-packed verdicts [rows, W] of a feature tree whose
+        rows are a multiple of chunk, one chunk per lax.map step."""
+        chunked = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1, chunk) + a.shape[1:]), feats)
+        packed = jax.lax.map(
+            lambda ch: _pack_chunk(self._eval(ch, params, table, derived)),
+            chunked)
+        return packed.reshape((-1,) + packed.shape[2:])
+
     def fires(self, feats: dict, params: dict, match_table: np.ndarray,
               derived: Optional[dict] = None) -> np.ndarray:
         """-> bool [N, C]."""
@@ -981,13 +912,15 @@ class CompiledTemplate:
     def fires_chunked(self, feats: dict, params: dict,
                       match_table: np.ndarray,
                       derived: Optional[dict] = None,
-                      chunk: int = 8192,
+                      chunk: Optional[int] = None,
                       n_cons: Optional[int] = None) -> np.ndarray:
-        """Chunk the N axis so [N, C, K...] intermediates stay bounded.
+        """Dense bool [N, C] verdicts, the N axis chunked (CHUNK rows by
+        default) so [N, C, K...] intermediates stay bounded.
 
         Single dispatch: inputs live on device whole, the chunk loop is a
         lax.map inside the jitted fn (no per-chunk host→device
-        transfers).
+        transfers), and the verdicts return bit-packed over C (32x
+        smaller device→host transfer).
 
         n_cons bounds the valid constraint columns: the C axis may carry
         power-of-two bucket padding (driver._prepare_eval) so constraint
@@ -995,453 +928,139 @@ class CompiledTemplate:
         columns replicate the last real constraint and are sliced off
         here."""
         derived = derived or {}
+        chunk = chunk or CHUNK
         c = _param_c(params)
         if n_cons is not None:
             c = min(c, n_cons)
-        if not feats:
-            # parameter-only program: no object slots to chunk over
-            return self.fires(feats, params, match_table, derived)[:, :c]
-        n = next(iter(next(iter(feats.values())).values())).shape[0]
+        n = _rows(feats)
         if n <= chunk:
+            # one chunk, or a parameter-only program with no rows
             return self.fires(feats, params, match_table, derived)[:, :c]
         if n % chunk:
-            pad_n = ((n + chunk - 1) // chunk) * chunk
-            feats = jax.tree_util.tree_map(
-                lambda a: jnp.pad(a, [(0, pad_n - n)] + [(0, 0)] *
-                                  (a.ndim - 1)), feats)
-        out = self._fn_scan(feats, params, match_table, derived, chunk)
-        # slice the bit-unpack padding back to the true C
-        return np.asarray(out)[:n, :c]
-
-    def _fn_scan(self, feats, params, match_table, derived, chunk: int):
-        """Verdicts return bit-packed over C (32x smaller device→host
-        transfer)."""
-        packed = np.asarray(self._packed_device(feats, params, match_table,
-                                                derived, chunk))
-        # unpack on host (vectorized)
+            feats = _pad_rows(feats, -(-n // chunk) * chunk)
+        packed = np.asarray(self._scan_jit(chunk)(feats, params,
+                                                  match_table, derived))
         bits = (packed[..., None] >> np.arange(32, dtype=np.uint32)) & 1
-        return bits.reshape(packed.shape[0], -1).astype(bool)
-
-    def _packed_device(self, feats, params, match_table, derived,
-                       chunk: int):
-        """Bit-packed verdicts [Npad, W] uint32, left on device."""
-        return self._scan_jit(chunk)(feats, params, match_table, derived)
+        return bits.reshape(packed.shape[0], -1).astype(bool)[:n, :c]
 
     def _scan_jit(self, chunk: int):
         fn = self._scan_cache.get(chunk)
         if fn is None:
             def run(feats, params, table, derived):
-                def reshape(a):
-                    return a.reshape((-1, chunk) + a.shape[1:])
-                chunked = jax.tree_util.tree_map(reshape, feats)
-
-                def body(ch):
-                    fires = self._eval(ch, params, table, derived)  # [chunk, C]
-                    c = fires.shape[-1]
-                    w = (c + 31) // 32
-                    pad = w * 32 - c
-                    if pad:
-                        fires = jnp.pad(fires, ((0, 0), (0, pad)))
-                    bits = fires.reshape(fires.shape[0], w, 32)
-                    weights = (jnp.uint32(1) << jnp.arange(32,
-                                                           dtype=jnp.uint32))
-                    return jnp.sum(
-                        jnp.where(bits, weights, jnp.uint32(0)), axis=-1,
-                        dtype=jnp.uint32)
-                outs = jax.lax.map(body, chunked)
-                return outs.reshape((-1,) + outs.shape[2:])
+                return self._packed(feats, params, table, derived, chunk)
             fn = self._ajit("scan", (chunk,), run)
             self._scan_cache[chunk] = fn
         return fn
 
     # ------------------------------------------------------ sparse verdicts
 
-    def fires_pairs(self, feats: dict, params: dict,
-                    match_table: np.ndarray,
-                    derived: Optional[dict] = None,
-                    chunk: int = 8192,
-                    n_true: Optional[int] = None,
-                    n_cons: Optional[int] = None
-                    ) -> tuple[np.ndarray, np.ndarray]:
-        """-> (rows, cols): row-major-ordered firing (object, constraint)
-        index pairs.
+    def _sweep_jit(self, mesh, chunk: int, slab: int, pcap: int):
+        """The sweep program, one per (mesh, chunk, slab, pcap): clamped
+        dynamic-slice of `slab` rows of the FULL device-resident feature
+        tree at a traced `start`, chunked sweep and bit-pack, masking of
+        padding rows (>= n_valid) and of the clamp overlap (< start,
+        already emitted by the previous slab), and the device pair decode
+        (_pair_expand) into one [pcap+1, 2] block. Every slab of a sweep
+        reuses the one program.
 
-        Audits are ~99% rejects, so the dense [N, C] verdict tensor is
-        nearly all False; extracting the firing pairs ON DEVICE
-        (population count + fixed-capacity nonzero) and transferring only
-        those indices moves ~10x fewer bytes than even the bit-packed
-        tensor. The nonzero capacity is
-        remembered from the previous sweep (steady-state audits transfer
-        once); a capacity miss re-gathers at the exact count.
-
-        n_true bounds the valid rows (feats may carry extraction bucket
-        padding — empty padding objects can legitimately fire absence
-        clauses, so they are masked out ON DEVICE before the count, or
-        they would flood the gather capacity)."""
-        derived = derived or {}
-        c = _param_c(params)
-        if n_cons is not None:
-            c = min(c, n_cons)
-        if not feats:
-            fires = self.fires(feats, params, match_table, derived)
-            rows, cols = np.nonzero(fires[:, :c])
-            return rows.astype(np.int64), cols.astype(np.int64)
-        n = next(iter(next(iter(feats.values())).values())).shape[0]
-        if n_true is not None:
-            n = min(n, n_true)
-        if next(iter(next(iter(feats.values())).values())).shape[0] <= chunk:
-            fires = self.fires(feats, params, match_table, derived)
-            rows, cols = np.nonzero(fires[:n, :c])
-            return rows.astype(np.int64), cols.astype(np.int64)
-        st = self._pairs_dispatch_mono(feats, params, match_table, derived,
-                                       chunk, n, c)
-        return self._pairs_consume_mono(st)
-
-    def _pairs_dispatch_mono(self, feats, params, match_table, derived,
-                             chunk: int, n: int,
-                             c: Optional[int] = None):
-        """ASYNC dispatch of the monolithic packed sweep + device pair
-        decode; _pairs_consume_mono syncs (with the capacity-retry
-        loop)."""
-        n_feat = next(iter(next(iter(feats.values())).values())).shape[0]
-        if n_feat % chunk:
-            pad_n = ((n_feat + chunk - 1) // chunk) * chunk
-            feats = jax.tree_util.tree_map(
-                lambda a: jnp.pad(a, [(0, pad_n - n_feat)] + [(0, 0)] *
-                                  (a.ndim - 1)), feats)
-        packed = self._packed_device(feats, params, match_table, derived,
-                                     chunk)
-        if c is None:
-            c = _param_c(params)
-        rcap = self._rows_cap
-        dev = self._gather_pairs(packed, n, c, rcap)
-        return (packed, n, rcap, dev, c)
-
-    def _pairs_consume_mono(self, st):
-        packed, n, rcap, dev, c = st
-        arr = np.asarray(dev)  # sync
-        pcount = int(arr[0, 0])
-        while pcount > rcap:
-            rcap = max(rcap, 1 << (pcount - 1).bit_length())
-            arr = np.asarray(self._gather_pairs(packed, n, c, rcap))
-            pcount = int(arr[0, 0])
-        self._rows_cap = max(256, (1 << (pcount - 1).bit_length())
-                             if pcount > 1 else 256)
-        return _decode_pair_blocks(arr, pcount)
-
-    def _slab_pairs_jit(self, chunk: int, slab: int, pcap: int):
-        """One fused jit per (chunk, slab, pcap): clamped dynamic-slice
-        of the FULL device-resident feature tree at a traced `start`,
-        chunked sweep, bit-pack, and dense pair decode (_pair_expand),
-        returning one [pcap+1, 2] pair block. One device dispatch + one
-        fetch per slab — no per-leaf host pad/slice op storms, no scalar
-        count fetches, each of which costs a device roundtrip — and the
-        host receives (row, constraint) INDEX arrays, no
-        bitmask unpacking."""
-        key = ("slabp", chunk, slab, pcap)
-        fn = self._pairs_cache.get(key)
+        With a mesh, the same function runs under shard_map over the
+        "data" axis: each device slices its own rows, stamps global row
+        indices from its axis_index, and the out spec P("data")
+        concatenates the per-shard blocks, so one dispatch and one fetch
+        serve a slab of the whole mesh. No collective runs: the object
+        axis is pure data parallelism."""
+        key = (None if mesh is None else id(mesh), chunk, slab, pcap)
+        fn = self._sweep_cache.get(key)
         if fn is not None:
             return fn
 
-        def run(feats, params, table, derived, start, n_valid, c):
-            leaf = next(iter(next(iter(feats.values())).values()))
-            n_feat = leaf.shape[0]  # static
-            cs = jnp.minimum(start, n_feat - slab)
+        def sweep(feats, params, table, derived, start, n_valid, c, shard):
+            n_rows = _rows(feats)  # static: one shard's rows on a mesh
+            cs = jnp.minimum(start, n_rows - slab)
             sl = jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_slice_in_dim(a, cs, slab, axis=0),
                 feats)
-            chunked = jax.tree_util.tree_map(
-                lambda a: a.reshape((-1, chunk) + a.shape[1:]), sl)
+            packed = self._packed(sl, params, table, derived, chunk)
+            local = cs + jnp.arange(slab, dtype=jnp.int32)
+            row0 = shard * n_rows
+            valid = (row0 + local < n_valid) & (local >= start)
+            return _pair_expand(packed, valid, row0 + cs, c, pcap)
 
-            def body(ch):
-                fires = self._eval(ch, params, table, derived)  # [chunk, C]
-                cc = fires.shape[-1]
-                w = (cc + 31) // 32
-                pad = w * 32 - cc
-                if pad:
-                    fires = jnp.pad(fires, ((0, 0), (0, pad)))
-                bits = fires.reshape(fires.shape[0], w, 32)
-                weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-                return jnp.sum(jnp.where(bits, weights, jnp.uint32(0)),
-                               axis=-1, dtype=jnp.uint32)
+        if mesh is None:
+            def run(feats, params, table, derived, start, n_valid, c):
+                return sweep(feats, params, table, derived, start, n_valid,
+                             c, 0)
+            fn = self._ajit("slabp", (chunk, slab, pcap), run)
+        else:
+            from jax.sharding import PartitionSpec as P
 
-            packed = jax.lax.map(body, chunked)
-            packed = packed.reshape((slab,) + packed.shape[2:])
-            rows_global = cs + jnp.arange(slab, dtype=jnp.int32)
-            # mask extraction padding (>= n_valid) AND the clamp overlap
-            # (< start): overlap rows were already emitted by the
-            # previous slab
-            valid = (rows_global < n_valid) & (rows_global >= start)
-            return _pair_expand(packed, valid, cs, c, pcap)
+            def local(feats, params, table, derived, start, n_valid, c):
+                return sweep(feats, params, table, derived, start, n_valid,
+                             c, jax.lax.axis_index("data"))
 
-        fn = self._ajit("slabp", (chunk, slab, pcap), run)
-        self._pairs_cache[key] = fn
+            def run(feats, params, table, derived, start, n_valid, c):
+                fspec = jax.tree_util.tree_map(
+                    lambda a: P("data", *([None] * (a.ndim - 1))), feats)
+                rep = lambda tree: jax.tree_util.tree_map(
+                    lambda a: P(*([None] * a.ndim)), tree)
+                return _shard_map_wrap(
+                    local, mesh=mesh,
+                    in_specs=(fspec, rep(params), rep(table), rep(derived),
+                              P(), P(), P()),
+                    out_specs=P("data", None),
+                )(feats, params, table, derived, start, n_valid, c)
+            fn = self._ajit(
+                "mesh-slabp",
+                (chunk, slab, pcap, tuple(sorted(mesh.shape.items()))), run)
+        self._sweep_cache[key] = fn
         return fn
 
     def fires_pairs_dispatch(self, feats: dict, params: dict,
                              match_table: np.ndarray,
                              derived: Optional[dict] = None,
-                             chunk: int = 8192,
-                             slab: int = 32768,
                              n_true: Optional[int] = None,
-                             n_cons: Optional[int] = None):
-        """Dispatch every slab kernel NOW (async); the returned handle's
-        .pairs() iterator syncs and decodes slab-by-slab. Callers can
-        dispatch MANY templates' sweeps before consuming any — the audit
-        overlaps every kind's device work with every kind's host
-        materialization."""
+                             n_cons: Optional[int] = None,
+                             mesh=None) -> _PairSweep:
+        """Dispatch a sweep's firing-pair extraction NOW (async) and
+        return its handle, whose .pairs() syncs and decodes slab by slab
+        (_PairSweep). Callers can dispatch MANY templates' sweeps before
+        consuming any: the audit overlaps every kind's device work with
+        every kind's host materialization.
+
+        Audits are ~99% rejects, so the dense [N, C] verdicts are nearly
+        all False; each slab's verdicts are decoded to (row, constraint)
+        index pairs ON DEVICE and one fetch moves only those. n_true
+        bounds the valid rows: feats may carry extraction bucket padding,
+        and empty padding objects can fire absence clauses, so they are
+        masked on device before the count. n_cons bounds the valid
+        columns (C-bucket padding). With `mesh`, the feature rows split
+        over its "data" axis, whose size must divide them."""
         derived = derived or {}
-        n_feat = (next(iter(next(iter(feats.values())).values())).shape[0]
-                  if feats else 0)
-        n = n_feat
-        if n_true is not None:
-            n = min(n, n_true)
-        if not feats or n <= slab or n_feat < slab:
-            return _EagerPairs(self, feats, params, match_table, derived,
-                               chunk, n_true, n_cons)
         c = _param_c(params)
         if n_cons is not None:
             c = min(c, n_cons)
-        n_slabs = (n + slab - 1) // slab
-        rcap = self._rows_cap
-        fn = self._slab_pairs_jit(chunk, slab, rcap)
-        pend = [(rcap, fn(feats, params, match_table, derived,
-                          np.int32(k * slab), np.int32(n), np.int32(c)))
-                for k in range(n_slabs)]
-        return _SlabPairs(self, pend, feats, params, match_table, derived,
-                          chunk, slab, n, c)
-
-    def _mesh_pairs_jit(self, mesh, chunk: int, pcap: int):
-        """One fused SPMD program per (mesh, chunk, per-shard pcap):
-        shard_map over the mesh's "data" axis — each device sweeps its
-        contiguous N/D row block (chunked lax.map, same eval body as the
-        single-device sweep), bit-packs verdicts over C, masks padding
-        rows by GLOBAL row index, and decodes its local firing pairs to
-        dense (row, constraint) indices at capacity pcap (_pair_expand).
-        Output spec P("data") concatenates the per-shard [pcap+1, 2]
-        pair blocks, so the host pays ONE fetch for the whole mesh and
-        does no bit unpacking. No cross-device collective during
-        evaluation: the object axis is pure data parallelism;
-        aggregation happens on host from per-shard blocks (counts ride
-        in each block header)."""
-        key = ("meshp", id(mesh), chunk, pcap)
-        fn = self._pairs_cache.get(key)
-        if fn is not None:
-            return fn
-        from jax.sharding import PartitionSpec as P
-
-        def local(feats_l, params, table, derived, n_valid, c):
-            leaf = next(iter(next(iter(feats_l.values())).values()))
-            n_loc = leaf.shape[0]  # static: N // data axis size
-            chunked = jax.tree_util.tree_map(
-                lambda a: a.reshape((-1, chunk) + a.shape[1:]), feats_l)
-
-            def body(ch):
-                fires = self._eval(ch, params, table, derived)  # [chunk, C]
-                cc = fires.shape[-1]
-                w = (cc + 31) // 32
-                pad = w * 32 - cc
-                if pad:
-                    fires = jnp.pad(fires, ((0, 0), (0, pad)))
-                bits = fires.reshape(fires.shape[0], w, 32)
-                weights = (jnp.uint32(1) << jnp.arange(32,
-                                                       dtype=jnp.uint32))
-                return jnp.sum(jnp.where(bits, weights, jnp.uint32(0)),
-                               axis=-1, dtype=jnp.uint32)
-
-            packed = jax.lax.map(body, chunked)
-            packed = packed.reshape((n_loc,) + packed.shape[2:])
-            idx = jax.lax.axis_index("data")
-            row0 = idx * n_loc
-            rows_global = row0 + jnp.arange(n_loc, dtype=jnp.int32)
-            return _pair_expand(packed, rows_global < n_valid, row0, c,
-                                pcap)
-
-        def run(feats, params, table, derived, n_valid, c):
-            fspec = jax.tree_util.tree_map(
-                lambda a: P("data", *([None] * (a.ndim - 1))), feats)
-            rep = lambda tree: jax.tree_util.tree_map(
-                lambda a: P(*([None] * a.ndim)), tree)
-            return _shard_map_wrap(
-                local, mesh=mesh,
-                in_specs=(fspec, rep(params), rep(table), rep(derived),
-                          P(), P()),
-                out_specs=P("data", None),
-            )(feats, params, table, derived, n_valid, c)
-
-        fn = self._ajit(
-            "meshp", (chunk, pcap, tuple(sorted(mesh.shape.items()))), run)
-        self._pairs_cache[key] = fn
-        return fn
-
-    def _mesh_slab_pairs_jit(self, mesh, chunk: int, lslab: int,
-                             pcap: int):
-        """One fused SPMD program per (mesh, chunk, lslab, pcap): the
-        slab twin of _mesh_pairs_jit — each device dynamic-slices its
-        next `lslab` LOCAL rows at a traced `start` (so every slab of
-        a sweep reuses ONE compiled program), sweeps/bit-packs them,
-        and decodes its local firing pairs to dense (row, constraint)
-        indices at capacity pcap, with global row indices stamped from
-        axis_index. Out spec P("data") concatenates per-shard
-        [pcap+1, 2] blocks: one dispatch + one fetch per slab for the
-        whole mesh, nothing to unpack on host."""
-        key = ("mesh-slabp", id(mesh), chunk, lslab, pcap)
-        fn = self._pairs_cache.get(key)
-        if fn is not None:
-            return fn
-        from jax.sharding import PartitionSpec as P
-
-        def local(feats_l, params, table, derived, start, n_valid, c):
-            leaf = next(iter(next(iter(feats_l.values())).values()))
-            n_loc = leaf.shape[0]  # static: N // data axis size
-            cs = jnp.minimum(start, n_loc - lslab)
-            sl = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_slice_in_dim(a, cs, lslab,
-                                                       axis=0),
-                feats_l)
-            chunked = jax.tree_util.tree_map(
-                lambda a: a.reshape((-1, chunk) + a.shape[1:]), sl)
-
-            def body(ch):
-                fires = self._eval(ch, params, table, derived)
-                cc = fires.shape[-1]
-                w = (cc + 31) // 32
-                pad = w * 32 - cc
-                if pad:
-                    fires = jnp.pad(fires, ((0, 0), (0, pad)))
-                bits = fires.reshape(fires.shape[0], w, 32)
-                weights = (jnp.uint32(1) << jnp.arange(32,
-                                                       dtype=jnp.uint32))
-                return jnp.sum(jnp.where(bits, weights, jnp.uint32(0)),
-                               axis=-1, dtype=jnp.uint32)
-
-            packed = jax.lax.map(body, chunked)
-            packed = packed.reshape((lslab,) + packed.shape[2:])
-            idx = jax.lax.axis_index("data")
-            row0 = idx * n_loc
-            loc_rows = cs + jnp.arange(lslab, dtype=jnp.int32)
-            rows_global = row0 + loc_rows
-            # mask extraction padding (>= n_valid) AND the clamp
-            # overlap (< start): overlap rows were already emitted by
-            # the previous slab
-            valid = (rows_global < n_valid) & (loc_rows >= start)
-            return _pair_expand(packed, valid, row0 + cs, c, pcap)
-
-        def run(feats, params, table, derived, start, n_valid, c):
-            fspec = jax.tree_util.tree_map(
-                lambda a: P("data", *([None] * (a.ndim - 1))), feats)
-            rep = lambda tree: jax.tree_util.tree_map(
-                lambda a: P(*([None] * a.ndim)), tree)
-            return _shard_map_wrap(
-                local, mesh=mesh,
-                in_specs=(fspec, rep(params), rep(table), rep(derived),
-                          P(), P(), P()),
-                out_specs=P("data", None),
-            )(feats, params, table, derived, start, n_valid, c)
-
-        fn = self._ajit(
-            "mesh-slabp",
-            (chunk, lslab, pcap, tuple(sorted(mesh.shape.items()))), run)
-        self._pairs_cache[key] = fn
-        return fn
-
-    # the mesh slab loop engages once each shard holds at least this
-    # many multiples of the chunk (below it, one dispatch is cheaper
-    # than the per-slab fetch round-trips); slabs aim for ~MESH_SLABS
-    # per sweep
-    MESH_SLAB_MIN_CHUNKS = 8
-    MESH_SLABS = 8
-
-    def fires_pairs_mesh_dispatch(self, feats: dict, params: dict,
-                                  match_table: np.ndarray, mesh,
-                                  derived: Optional[dict] = None,
-                                  chunk: int = 8192,
-                                  n_true: Optional[int] = None,
-                                  slab: Optional[int] = None,
-                                  n_cons: Optional[int] = None):
-        """Mesh-sharded form of fires_pairs_dispatch: dispatch the SPMD
-        sweep NOW (async), return a handle whose .pairs() syncs and
-        yields per-shard (rows, cols). Requires the feature N axis
-        divisible by the mesh's "data" axis size (callers pad to a
-        power-of-two bucket and gate on divisibility).
-
-        Large sweeps take the double-buffered SLAB loop (_MeshSlabPairs:
-        per-shard materialization overlaps the device sweep of the next
-        slab, jax.block_until_ready only at slab boundaries); small
-        sweeps keep the single monolithic dispatch. `slab` overrides the
-        LOCAL (per-shard) slab size — must divide the per-shard row
-        count and be a multiple of the chunk."""
-        derived = derived or {}
-        n_feat = (next(iter(next(iter(feats.values())).values())).shape[0]
-                  if feats else 0)
-        n_data = mesh.shape["data"]
-        if not feats or n_feat % n_data:
-            raise ValueError(f"N={n_feat} not shardable over data={n_data}")
+        if not feats:
+            # a program that reads no object field: one verdict row
+            rows, cols = np.nonzero(
+                self.fires(feats, params, match_table, derived)[:, :c])
+            return _PairSweep(self, None, None, 0, 0, 0, done=[
+                (None, rows.astype(np.int64), cols.astype(np.int64))])
+        n_feat = _rows(feats)
         n = n_feat if n_true is None else min(n_feat, n_true)
-        n_loc = n_feat // n_data
-        chunk_eff = min(chunk, n_loc)
-        if n_loc % chunk_eff:
-            raise ValueError(f"n_loc={n_loc} not divisible by "
-                             f"chunk={chunk_eff}")
-        c = _param_c(params)
-        if n_cons is not None:
-            c = min(c, n_cons)
-        lslab = slab
-        if lslab is None and \
-                n_loc >= self.MESH_SLAB_MIN_CHUNKS * chunk_eff:
-            # power-of-two extraction buckets make this exact: aim for
-            # MESH_SLABS slabs, never below one chunk each
-            lslab = max(chunk_eff, n_loc // self.MESH_SLABS)
-        if lslab is not None and lslab < n_loc:
-            if n_loc % lslab or lslab % chunk_eff:
-                raise ValueError(
-                    f"slab={lslab} must divide n_loc={n_loc} and be a "
-                    f"multiple of chunk={chunk_eff}")
-            return _MeshSlabPairs(
-                self, mesh, chunk_eff, lslab, n_loc // lslab,
-                self._rows_cap_mesh,
-                (feats, params, match_table, derived, np.int32(n), c))
-        rcap = self._rows_cap_mesh
-        fn = self._mesh_pairs_jit(mesh, chunk_eff, rcap)
-        dev = fn(feats, params, match_table, derived, np.int32(n),
-                 np.int32(c))
-        return _MeshPairs(self, mesh, dev, rcap, chunk_eff,
-                          (feats, params, match_table, derived,
-                           np.int32(n), c))
-
-    def fires_pairs_slabbed(self, feats: dict, params: dict,
-                            match_table: np.ndarray,
-                            derived: Optional[dict] = None,
-                            chunk: int = 8192,
-                            slab: int = 32768,
-                            n_true: Optional[int] = None,
-                            n_cons: Optional[int] = None):
-        """Yield row-major (rows, cols) firing pairs per N-axis slab.
-        See fires_pairs_dispatch; this is dispatch + immediate consume."""
-        yield from self.fires_pairs_dispatch(
-            feats, params, match_table, derived, chunk=chunk, slab=slab,
-            n_true=n_true, n_cons=n_cons).pairs()
-
-    def _gather_pairs(self, packed, n: int, c: int, pcap: int):
-        """Device firing-PAIR gather: one [pcap+1, 2] uint32 block —
-        header row carrying the pair count, then the (row, constraint)
-        index pairs row-major (see _pair_expand).
-
-        Audits are ~99.99% rejects, so the dense index pairs are tiny,
-        the whole result is ONE device->host fetch (scalar-count-then-
-        data would pay two roundtrips), and the host does no bit
-        unpacking at all.
-        Rows >= n are extraction padding, masked before counting; cols
-        >= c are C-bucket padding, masked on device too."""
-        return self._pairs_jit(pcap)(packed, np.int32(n), np.int32(c))
-
-    def _pairs_jit(self, pcap: int):
-        fn = self._pairs_cache.get(("pairsg", pcap))
-        if fn is None:
-            def run(packed, n, c):
-                npad = packed.shape[0]
-                valid = jnp.arange(npad, dtype=jnp.int32) < n
-                return _pair_expand(packed, valid, jnp.int32(0), c, pcap)
-            fn = self._ajit("pairsg", (pcap,), run)
-            self._pairs_cache[("pairsg", pcap)] = fn
-        return fn
+        shards = 1 if mesh is None else mesh.shape["data"]
+        if n_feat % shards:
+            raise ValueError(f"N={n_feat} not shardable over data={shards}")
+        chunk, slab = _slab_plan(n_feat, n, shards)
+        n_loc = n_feat // shards
+        if mesh is None:
+            n_slabs = -(-n // slab)
+            if slab > n_feat:
+                feats = _pad_rows(feats, slab)
+        else:
+            n_slabs = n_loc // slab
+            if n_loc % slab or slab % chunk:
+                raise ValueError(f"n_loc={n_loc} does not split into slabs "
+                                 f"of {slab} rows, chunks of {chunk}")
+        return _PairSweep(self, mesh, (feats, params, match_table, derived,
+                                       np.int32(n), np.int32(c)),
+                          chunk, slab, n_slabs)

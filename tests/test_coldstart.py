@@ -158,12 +158,12 @@ def test_cbucket_library_edit_within_bucket_zero_compiles(tmp_path):
     assert d["fresh"] == 0 and d["cache"] == 0 and d["aot"] == 0
 
 
-def test_cbucket_bit_equal_unbucketed_including_mesh_slab():
+def test_cbucket_bit_equal_unbucketed_including_mesh_slab(monkeypatch):
     """Bucketed C results must be bit-equal to the unbucketed shapes
     (GATEKEEPER_TPU_CBUCKET=0) and the interpreter — on the mesh SLAB
     path too, where the C slicing rides the per-shard decode."""
     from gatekeeper_tpu.client import RegoDriver
-    from gatekeeper_tpu.ir.evaljax import _MeshSlabPairs
+    from gatekeeper_tpu.ir import evaljax
 
     N, NC = 4096, 5
     assert _bucket(NC) != NC, "non-vacuous: C must actually pad"
@@ -173,8 +173,7 @@ def test_cbucket_bit_equal_unbucketed_including_mesh_slab():
     assert dm.cbucket
     dm.MESH_MIN_REVIEWS = 64
     dm._dev_batch_lat_s = 1e-4
-    dm.sweep_chunk = 64
-    dm.mesh_slab_local = 256  # n_loc = 512 -> 2 slabs per shard
+    monkeypatch.setattr(evaljax, "CHUNK", 64)  # n_loc 512: 8 slabs
     cm = _labels_client(dm, N, NC)
     handles = []
     orig = dm._dispatch_handle
@@ -188,8 +187,8 @@ def test_cbucket_bit_equal_unbucketed_including_mesh_slab():
     got_mesh = _key(cm.audit().results())
     dm._dispatch_handle = orig
     assert dm.last_audit_path == "mesh(data=8)", dm.last_audit_path
-    assert any(isinstance(h, _MeshSlabPairs) for h in handles), \
-        "audit did not take the mesh slab loop"
+    assert any(h._mesh is not None and h._n_slabs > 1
+               for h in handles), "audit did not take the mesh slab loop"
 
     os.environ["GATEKEEPER_TPU_CBUCKET"] = "0"
     try:
@@ -280,19 +279,20 @@ def test_aot_warm_boot_adopts_sweep_sigs(tmp_path, fresh_xla_cache):
     assert not d2._eval_counts.get(("K8sRequiredLabels", "interp"))
 
 
-# the default chunk makes the 2048-row sweep one lazy chunk (run under
-# the guard by _dispatch_guarded); 256 makes it a slab dispatch
+# the default chunk sweeps the 2048 rows as one chunk; 256 as two slabs
 @pytest.mark.parametrize("sweep_chunk", [8192, 256])
 def test_adopted_sig_without_executable_serves_host_not_inline_compile(
-        tmp_path, fresh_xla_cache, sweep_chunk):
+        tmp_path, fresh_xla_cache, sweep_chunk, monkeypatch):
     """A warm-boot-adopted sweep signature whose backing executable is
     gone (store GC'd, save refused on the previous boot) must NOT stall
     the serving path on an inline XLA compile: the sig is un-adopted,
     the host/interpreter answers this round, and the program re-warms
     in the background."""
+    from gatekeeper_tpu.ir import evaljax
+
+    monkeypatch.setattr(evaljax, "CHUNK", sweep_chunk)
     aot_dir = str(tmp_path / "aot")
     d1 = _single_device_driver(aot_dir=aot_dir)
-    d1.sweep_chunk = sweep_chunk
     c1 = _labels_client(d1, 2048, 5)
     got1 = _key(c1.audit().results())
     assert d1.aot.programs_count() >= 1
@@ -307,7 +307,6 @@ def test_adopted_sig_without_executable_serves_host_not_inline_compile(
     os.environ["GATEKEEPER_TPU_ASYNC_COMPILE"] = "1"
     try:
         d2 = _single_device_driver(aot_dir=aot_dir)
-        d2.sweep_chunk = sweep_chunk
         # pin the host model fast so the block-when-cheaper rule picks
         # the host fallback (the guard's outcome is then observable as
         # an interp eval instead of a waited-out background warm)

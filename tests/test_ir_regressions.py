@@ -265,29 +265,36 @@ violation[{"msg": "prefix"}] {
         ["core-x"]
 
 
-def test_fires_pairs_matches_dense_with_padding_and_regather():
+def test_fires_pairs_matches_dense_with_padding_and_regather(monkeypatch):
     """The sparse device pair-extraction path must agree exactly with the
     dense verdict tensor — including extraction bucket padding (empty
     padding objects legitimately fire absence clauses and must be masked
-    on device) and a deliberately undersized gather capacity (forces the
-    count-miss re-gather loop)."""
+    on device) and a deliberately undersized pair capacity (forces the
+    count-miss re-run loop)."""
     import numpy as np
+    from gatekeeper_tpu.ir import evaljax
     from gatekeeper_tpu.parallel.workload import build_eval_setup
 
+    monkeypatch.setattr(evaljax, "CHUNK", 1024)
     n, c = 3000, 40
     driver, ct, feats, params, table, derived, reviews, cons = \
         build_eval_setup(n, c, n_bucket=4096, violate_frac=0.3)
-    dense = ct.fires_chunked(feats, params, table, derived, chunk=1024)
+    dense = ct.fires_chunked(feats, params, table, derived)
     want = np.nonzero(dense[:n])
-    ct._rows_cap = 16  # force at least one capacity re-gather
-    rows, cols = ct.fires_pairs(feats, params, table, derived, chunk=1024,
-                                n_true=n)
+
+    def pairs():
+        blocks = list(ct.fires_pairs_dispatch(feats, params, table, derived,
+                                              n_true=n).pairs())
+        return (np.concatenate([b[1] for b in blocks]),
+                np.concatenate([b[2] for b in blocks]))
+
+    ct._pcap[False] = 16  # force at least one capacity re-run
+    rows, cols = pairs()
     assert rows.shape == want[0].shape
     assert (rows == want[0]).all() and (cols == want[1]).all()
-    assert ct._rows_cap >= len(np.unique(rows))
+    assert ct._pcap[False] >= len(np.unique(rows))
     # steady state: second call reuses the remembered capacity
-    rows2, cols2 = ct.fires_pairs(feats, params, table, derived, chunk=1024,
-                                  n_true=n)
+    rows2, cols2 = pairs()
     assert (rows2 == rows).all() and (cols2 == cols).all()
 
 

@@ -243,10 +243,13 @@ def test_driver_mesh_audit_equals_single_device():
     assert len(got_mesh2) == len(got_mesh) - 1
 
 
-def test_driver_mesh_gather_capacity_retry():
+def test_driver_mesh_gather_capacity_retry(monkeypatch):
     """Every object violating: the per-shard firing-row gather must
     overflow its initial capacity and re-run at a larger one without
     losing rows."""
+    from gatekeeper_tpu.ir import evaljax
+
+    monkeypatch.setattr(evaljax, "ALL_PAIRS", 0)  # capacity-bound slabs
     dm = _mesh_driver()
     cm = Backend(dm).new_client([K8sValidationTarget()])
     from gatekeeper_tpu import policies
@@ -264,14 +267,17 @@ def test_driver_mesh_gather_capacity_retry():
     assert dm.last_audit_path == "mesh(data=8)"
     assert len(out) == N, f"{len(out)} != {N} (rows lost in retry?)"
     ct = dm.compiled_for("K8sRequiredLabels")
-    assert ct._rows_cap_mesh >= 512
+    assert ct._pcap[True] >= 512
 
 
-def test_driver_mesh_gather_capacity_ratchets():
+def test_driver_mesh_gather_capacity_ratchets(monkeypatch):
     """Alternating small/large mesh sweeps: the per-shard gather
     capacity must RATCHET (like the single-device slab path) instead of
     resetting to each sweep's count — a shrink must not make the next
     grow re-trip the overflow re-run."""
+    from gatekeeper_tpu.ir import evaljax
+
+    monkeypatch.setattr(evaljax, "ALL_PAIRS", 0)  # capacity-bound slabs
     dm = _mesh_driver()
     cm = Backend(dm).new_client([K8sValidationTarget()])
     from gatekeeper_tpu import policies
@@ -288,7 +294,7 @@ def test_driver_mesh_gather_capacity_ratchets():
     assert len(cm.audit().results()) == N
     assert dm.last_audit_path == "mesh(data=8)"
     ct = dm.compiled_for("K8sRequiredLabels")
-    cap_grown = ct._rows_cap_mesh
+    cap_grown = ct._pcap[True]
     assert cap_grown >= 512
 
     def relabel(owner: bool):
@@ -307,20 +313,20 @@ def test_driver_mesh_gather_capacity_ratchets():
     relabel(owner=True)  # shrink: ~0 firing rows
     assert cm.audit().results() == []
     assert dm.last_audit_path == "mesh(data=8)"
-    assert ct._rows_cap_mesh >= cap_grown, \
+    assert ct._pcap[True] >= cap_grown, \
         "gather capacity shrank after a small sweep"
 
     relabel(owner=False)  # grow again: 512 firing rows per shard
     jit_calls = []
-    orig = ct._mesh_pairs_jit
+    orig = ct._sweep_jit
 
     def counting(*a, **k):
         jit_calls.append(a)
         return orig(*a, **k)
 
-    ct._mesh_pairs_jit = counting
+    ct._sweep_jit = counting
     out = cm.audit().results()
-    ct._mesh_pairs_jit = orig
+    ct._sweep_jit = orig
     assert dm.last_audit_path == "mesh(data=8)"
     assert len(out) == N
     # dispatch resolves the jit exactly once; with the pre-ratchet reset
@@ -395,21 +401,22 @@ def test_sharded_inventory_join_membership():
     assert want.any() and not want.all(), "non-vacuous membership split"
 
 
-def test_driver_mesh_slab_loop_equals_mono():
+def test_driver_mesh_slab_loop_equals_mono(monkeypatch):
     """The double-buffered mesh SLAB loop (per-shard materialization
     overlapping the next slab's device sweep) must produce exactly the
-    monolithic mesh dispatch's results, in the same global row-major
-    order, across multiple slabs per shard — including a gather
+    single-device sweep's results, in the same global row-major
+    order, across multiple slabs per shard — including a pair
     capacity overflow inside one slab."""
-    from gatekeeper_tpu import policies
-    from gatekeeper_tpu.ir.evaljax import _MeshSlabPairs
+    from gatekeeper_tpu.ir import evaljax
 
     N = 16384
+    monkeypatch.setattr(evaljax, "CHUNK", 256)  # n_loc 2048: 8 slabs
+    monkeypatch.setattr(evaljax, "ALL_PAIRS", 0)  # capacity-bound slabs
     dm = _mesh_driver()
-    dm.sweep_chunk = 256
-    dm.mesh_slab_local = 512  # n_loc = 2048 -> 4 slabs per shard
     cm = Backend(dm).new_client([K8sValidationTarget()])
     _labels_workload(cm, N)
+    # ~170 firing rows in a 256-row slab of a shard: overflows 64
+    dm.compiled_for("K8sRequiredLabels")._pcap[True] = 64
     handles = []
     orig = dm._dispatch_handle
 
@@ -422,8 +429,10 @@ def test_driver_mesh_slab_loop_equals_mono():
     got = cm.audit().results()
     dm._dispatch_handle = orig
     assert dm.last_audit_path == "mesh(data=8)", dm.last_audit_path
-    assert any(isinstance(h, _MeshSlabPairs) for h in handles), \
-        "audit did not take the slab loop"
+    assert any(h._mesh is not None and h._n_slabs > 1
+               for h in handles), "audit did not take the slab loop"
+    assert dm.compiled_for("K8sRequiredLabels")._pcap[True] > 64, \
+        "no slab overflowed its pair capacity"
 
     ds = TpuDriver()
     ds._mesh = None
@@ -441,27 +450,69 @@ def test_driver_mesh_slab_loop_equals_mono():
     assert len(got) == N - (N + 2) // 3, "non-vacuous"
 
 
-def test_mesh_slab_dispatch_direct_overflow_and_order():
-    """fires_pairs_mesh_dispatch with a forced small slab: every object
-    firing overflows the initial 256-per-shard gather capacity inside
-    each slab; the retry must lose no rows and the capacity must
-    ratchet."""
+def test_mesh_slab_dispatch_direct_overflow_and_order(monkeypatch):
+    """A mesh sweep with a forced small slab: every object firing
+    overflows an 8-per-shard pair capacity inside each slab; the retry
+    must lose no rows and the capacity must ratchet."""
+    from gatekeeper_tpu.ir import evaljax
+
+    monkeypatch.setattr(evaljax, "CHUNK", 64)  # n_loc=512 -> 8 slabs
+    monkeypatch.setattr(evaljax, "ALL_PAIRS", 0)  # capacity-bound slabs
     driver, ct, feats, params, table, derived, reviews, cons = \
         build_eval_setup(n_objects=4096, n_constraints=1,
                          violate_frac=1.0)
     mesh = make_mesh(devices=jax.devices()[:8], data=8, model=1)
     n_feat = next(iter(next(iter(feats.values())).values())).shape[0]
     assert n_feat % 8 == 0
-    ct._rows_cap_mesh = 8  # force the per-slab overflow retry
-    handle = ct.fires_pairs_mesh_dispatch(
-        feats, params, table, mesh, derived, chunk=128,
-        n_true=len(reviews), slab=128)  # n_loc=512 -> 4 slabs
-    rows = np.concatenate([r for r, _c in handle.pairs()])
+    ct._pcap[True] = 8  # force the per-slab overflow retry
+    handle = ct.fires_pairs_dispatch(feats, params, table, derived,
+                                     n_true=len(reviews), mesh=mesh)
+    assert handle._n_slabs == 8
+    rows = np.concatenate([r for _s, r, _c in handle.pairs()])
     expected = ct.fires(feats, params, table, derived)[: len(reviews)]
     want_rows = np.flatnonzero(expected.any(axis=1))
     assert sorted(rows.tolist()) == want_rows.tolist()
     assert len(want_rows) > 64, "non-vacuous overflow workload"
-    assert ct._rows_cap_mesh > 8, "gather capacity did not ratchet"
+    assert ct._pcap[True] > 8, "pair capacity did not ratchet"
+
+
+# rows are padded to n_bucket[shards]; 3,008 rows on one device make
+# the second slab's clamped start overlap the first by 64 rows
+@pytest.mark.parametrize("n,n_bucket,slabs", [
+    (40, {1: 64, 4: 64}, {1: 1, 4: 1}),          # below one chunk
+    (200, {1: 256, 4: 256}, {1: 1, 4: 1}),       # a chunk to one slab
+    (3000, {1: 3008, 4: 4096}, {1: 2, 4: 8}),    # several slabs
+], ids=["below-a-chunk", "one-slab", "several-slabs"])
+@pytest.mark.parametrize("shards", [1, 4], ids=["one-device", "mesh4"])
+def test_pair_sweep_equals_dense_verdicts(monkeypatch, n, n_bucket, slabs,
+                                          shards):
+    """fires_pairs_dispatch against the dense fires_chunked verdicts,
+    exactly: extraction padding rows masked, C padding columns masked,
+    slab overlap emitted once, and every pair kept through a forced
+    capacity overflow, on one device and on the 4-device CPU mesh."""
+    from gatekeeper_tpu.ir import evaljax
+
+    monkeypatch.setattr(evaljax, "CHUNK", 64)
+    monkeypatch.setattr(evaljax, "ALL_PAIRS", 0)  # capacity-bound slabs
+    driver, ct, feats, params, table, derived, reviews, cons = \
+        build_eval_setup(n, 3, n_bucket=n_bucket[shards], violate_frac=0.5)
+    mesh = None if shards == 1 else \
+        make_mesh(devices=jax.devices()[:4], data=4, model=1)
+    want = np.nonzero(ct.fires_chunked(feats, params, table, derived)[:n])
+    ct._pcap[mesh is not None] = 1  # overflows where a slab fires twice
+    handle = ct.fires_pairs_dispatch(feats, params, table, derived,
+                                     n_true=n, mesh=mesh)
+    assert handle._n_slabs == slabs[shards]
+    blocks = list(handle.pairs())
+    assert {b[0] for b in blocks} == \
+        ({None} if mesh is None else set(range(shards)))
+    # a mesh's blocks come (slab, shard) ordered: reassemble by first row
+    blocks.sort(key=lambda b: b[1][0] if len(b[1]) else -1)
+    rows = np.concatenate([b[1] for b in blocks])
+    cols = np.concatenate([b[2] for b in blocks])
+    assert (rows == want[0]).all() and (cols == want[1]).all()
+    assert len(rows) == len(want[0]) > 1, "non-vacuous overflow workload"
+    assert ct._pcap[mesh is not None] > 1, "capacity did not ratchet"
 
 
 def test_review_batch_sparse_mesh_equals_interpreter():

@@ -20,6 +20,7 @@ import pytest
 from gatekeeper_tpu import policies
 from gatekeeper_tpu.client import Backend
 from gatekeeper_tpu.ir import TpuDriver
+from gatekeeper_tpu.ir.evaljax import _slab_plan
 from gatekeeper_tpu.target import K8sValidationTarget
 
 TARGET = "admission.k8s.gatekeeper.sh"
@@ -121,7 +122,7 @@ def test_slab_pairs_requiredlabels_131072x512(one_chip):
     constraints (bucket 512) through the single-device slab kernel."""
     drv, ct, (feats, enc, table, derived) = _labels_setup()
     assert next(iter(next(iter(enc.values())).values())).shape[0] == 512
-    fn = ct._slab_pairs_jit(8192, drv._sweep_slab(100_000), ct._rows_cap)
+    fn = ct._sweep_jit(None, *_slab_plan(131072, 100_000), ct._pcap[False])
     compiled = fn.lower(_shapes(feats, one_chip, 131072),
                         _shapes(enc, one_chip), _shapes(table, one_chip),
                         _shapes(derived, one_chip),
@@ -142,7 +143,7 @@ def test_slab_pairs_psp_capabilities_65536(one_chip):
               "allowedCapabilities": ["NET_BIND_SERVICE"],
               "requiredDropCapabilities": ["ALL"]}}}],
         synth_pods_psp(64, seed=0))
-    fn = ct._slab_pairs_jit(8192, drv._sweep_slab(50_000), ct._rows_cap)
+    fn = ct._sweep_jit(None, *_slab_plan(65536, 50_000), ct._pcap[False])
     compiled = fn.lower(_shapes(feats, one_chip, 65536),
                         _shapes(enc, one_chip), _shapes(table, one_chip),
                         _shapes(derived, one_chip),
@@ -210,8 +211,7 @@ def test_mesh_slab_pairs_2x2(topo):
                     mesh, P("data", *([None] * (a.ndim - 1))))), tree)
 
     n = 131072
-    lslab = max(8192, n // 4 // ct.MESH_SLABS)
-    fn = ct._mesh_slab_pairs_jit(mesh, 8192, lslab, ct._rows_cap_mesh)
+    fn = ct._sweep_jit(mesh, *_slab_plan(n, 100_000, 4), ct._pcap[True])
     compiled = fn.lower(data_split(feats, n), _shapes(enc, rep),
                         _shapes(table, rep), _shapes(derived, rep),
                         *_scalars(rep)).compile()

@@ -108,7 +108,8 @@ def main(argv=None) -> int:
 
         def sweep():
             pairs = 0
-            for rows, _cols in ct.fires_pairs_dispatch(*a, **kw).pairs():
+            for _shard, rows, _cols in ct.fires_pairs_dispatch(
+                    *a, **kw).pairs():
                 pairs += len(rows)
             return pairs
 
@@ -127,8 +128,7 @@ def main(argv=None) -> int:
         if kind in forced and hasattr(evaljax, "_sweep_layout"):
             rule = evaljax._sweep_layout
             evaljax._sweep_layout = lambda n, c: args.layout
-            ct._pairs_cache.clear()
-            ct._scan_cache.clear()
+            ct._sweep_cache.clear()
             fn, ct._fn = ct._fn, ct._ajit("eval", (), ct._eval)
             try:
                 harness.say("forced", kind=kind, layout=args.layout,
@@ -136,8 +136,7 @@ def main(argv=None) -> int:
             finally:
                 evaljax._sweep_layout = rule
                 ct._fn = fn
-                ct._pairs_cache.clear()
-                ct._scan_cache.clear()
+                ct._sweep_cache.clear()
     for (kind, tag), (fn, a) in sorted(programs.items()):
         if kind in hlo:
             with open(os.path.join(args.out, f"{kind}.{tag}.hlo.txt"),
