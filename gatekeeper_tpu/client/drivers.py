@@ -75,6 +75,65 @@ def split_group_version(gv: str) -> tuple[str, str]:
     return group, version
 
 
+def _note_candidate_rows(use: str, n: int) -> None:
+    try:
+        from ..control.metrics import report_candidate_rows
+
+        report_candidate_rows(use, n)
+    except Exception:  # metrics backend optional in embedders
+        pass
+
+
+class CandidateRows:
+    """The candidate rows of one kind's audit sweep, as a read-only
+    sequence over the inventory's review list: row i is
+    `reviews[cand[i]]`, so the view equals `[reviews[i] for i in
+    cand]` without building that list. `cand` is an ascending 1-D
+    integer numpy array. A steady-state audit resolves only its firing
+    rows (`take`); the whole list is built only where every row is
+    read, as feature extraction does on a cache miss (`tolist`,
+    iteration). Both uses count in
+    gatekeeper_tpu_audit_candidate_rows_total{use}."""
+
+    __slots__ = ("reviews", "cand")
+
+    def __init__(self, reviews: list, cand):
+        self.reviews = reviews
+        self.cand = cand
+
+    def __len__(self) -> int:
+        return len(self.cand)
+
+    def __getitem__(self, i: int):
+        _note_candidate_rows("resolved", 1)
+        return self.reviews[int(self.cand[i])]
+
+    def take(self, rows) -> list:
+        """The reviews at sweep rows `rows`, in their order."""
+        idx = self.cand[rows].tolist()
+        _note_candidate_rows("resolved", len(idx))
+        reviews = self.reviews
+        return [reviews[i] for i in idx]
+
+    def tolist(self) -> list:
+        idx = self.cand.tolist()
+        _note_candidate_rows("listed", len(idx))
+        reviews = self.reviews
+        return [reviews[i] for i in idx]
+
+    def __iter__(self):
+        return iter(self.tolist())
+
+
+def take_rows(seq, rows) -> list:
+    """`[seq[r] for r in rows]`, resolved in one batch for a
+    CandidateRows."""
+    if isinstance(seq, CandidateRows):
+        return seq.take(rows)
+    return [seq[r] for r in (rows.tolist() if hasattr(rows, "tolist")
+                             else rows)]
+
+
 class RegoDriver:
     """Interpreter-backed driver with native hook/matcher evaluation."""
 
@@ -775,12 +834,14 @@ class RegoDriver:
             msg) — the status writer never publishes past its limit,
             so the messages were pure waste.
 
+        `pair_reviews` is the sweep's candidate rows: a CandidateRows
+        view over the inventory's review list (the audit sweep), or a
+        list. Only the firing rows are resolved, in one batch.
         `cand`, when given, maps pair rows to global inventory-review
-        indices (rows index pair_reviews == [reviews[i] for i in
-        cand]), letting witness columns cache across sweeps on the
-        stable full review list. Results share constraint/details
-        structures (callers treat results as read-only, as they
-        already must for .constraint).
+        indices (row i is reviews[cand[i]]), letting witness columns
+        cache across sweeps on the stable full review list. Results
+        share constraint/details structures (callers treat results as
+        read-only, as they already must for .constraint).
 
         `fmemo`, when given, is the arg-pure function memo for this
         call instead of the kind's memo scoped to `inventory`: a caller
@@ -792,14 +853,16 @@ class RegoDriver:
         vec = self._vec_msgs(target, kind, cons, pair_reviews, rows, cols,
                              cand)
         fn = self._codegen_for(target, kind)
+        # the review of every firing pair, in pair order
+        pair_revs = take_rows(pair_reviews, rows)
         if fn is None and vec is None:
             out: list[Result] = []
-            for ri, ci in zip(rows, cols):
+            for review, ci in zip(pair_revs, cols):
                 c = cons[int(ci)]
                 spec = c.get("spec")
                 spec = spec if isinstance(spec, dict) else {}
                 out.extend(self._eval_template_violations(
-                    target, c, pair_reviews[int(ri)],
+                    target, c, review,
                     spec.get("enforcementAction") or "deny", inventory,
                     None))
             return out
@@ -880,7 +943,7 @@ class RegoDriver:
                                 msg="",
                                 metadata={"details": {}},
                                 constraint=plain[ci],
-                                review=pair_reviews[ri],
+                                review=pair_revs[j],
                                 enforcement_action=enforce[ci],
                             ))
                             continue
@@ -888,13 +951,13 @@ class RegoDriver:
                         msg=vec_msgs[j],
                         metadata={"details": vec_details},
                         constraint=plain[ci],
-                        review=pair_reviews[ri],
+                        review=pair_revs[j],
                         enforcement_action=enforce[ci],
                     ))
                     continue
             if ri != cur_ri:
                 cur_ri = ri
-                review = pair_reviews[ri]
+                review = pair_revs[j]
                 frz_review = self._freeze_review_audit(review)
                 ent = self._rmemo.get(kind)
                 if ent is None or ent[0] is not review:

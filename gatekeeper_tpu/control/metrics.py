@@ -709,6 +709,7 @@ DEMOTION_REASONS = ("audit-eval", "review-eval", "join-eval",
 COMPILE_OUTCOMES = ("ok", "error")
 AUDIT_SWEEP_PATHS = ("incremental", "full_resync", "full", "stream")
 MATERIALIZE_PATHS = ("vectorized", "exact", "capped")
+CANDIDATE_ROW_USES = ("resolved", "listed")
 JOIN_RENDER_SCOPES = ("holders", "inventory")
 SWEEP_LAYOUTS = ("rows", "constraints")
 CACHE_OUTCOMES = ("hit", "miss")
@@ -1172,6 +1173,20 @@ def report_materialize_pairs(path: str, n: int) -> None:
         REGISTRY.counter_add("gatekeeper_tpu_audit_materialize_pairs_total",
                              "Materialized firing pairs by message path",
                              float(n), path=path)
+
+
+def report_candidate_rows(use: str, n: int) -> None:
+    """Rows of an audit sweep's candidate view (client/drivers.py
+    CandidateRows) handed out, by use: "resolved" (rows looked up one
+    by one, such as the firing rows materialization renders), "listed"
+    (every candidate listed in bulk, as feature extraction does on a
+    cache miss). A steady-state audit moves "listed" by 0."""
+    if use not in CANDIDATE_ROW_USES:
+        use = LABEL_FOLD
+    if n > 0:
+        REGISTRY.counter_add("gatekeeper_tpu_audit_candidate_rows_total",
+                             "Audit sweep candidate rows handed out by use",
+                             float(n), use=use)
 
 
 def report_join_render(scope: str, n: int) -> None:

@@ -28,7 +28,8 @@ from typing import Any, Iterable, Optional
 
 import numpy as np
 
-from ..client.drivers import DriverError, RegoDriver
+from ..client.drivers import (CandidateRows, DriverError, RegoDriver,
+                              take_rows)
 from ..client.types import Result
 from ..ops.derived import (
     DerivedTables,
@@ -1921,7 +1922,8 @@ class TpuDriver(RegoDriver):
         match mask, candidates, and feature/parameter prep (cached by
         candidate set, so a second call is a cache hit). -> None when no
         review is a candidate, else (mask, cand, cand_reviews, use_mesh,
-        (feats, enc, table, derived))."""
+        (feats, enc, table, derived)); cand_reviews is a CandidateRows
+        view, so a cached audit lists no candidate."""
         timers = profiling.timers()
         with timers.phase("match", kind=kind):
             mask = self._match_mask(target, kind, cons, reviews, lookup_ns,
@@ -1929,7 +1931,7 @@ class TpuDriver(RegoDriver):
             cand = np.flatnonzero(mask.any(axis=1))
             if cand.size == 0:
                 return None
-            cand_reviews = [reviews[int(i)] for i in cand]
+            cand_reviews = CandidateRows(reviews, cand)
         use_mesh = self._mesh_shardable(len(cand_reviews))
         with timers.phase("encode", kind=kind):
             trees = self._prepare_eval(
@@ -2102,13 +2104,17 @@ class TpuDriver(RegoDriver):
             return None
         rows_np = np.asarray(rows, dtype=np.int64)
         cols_np = np.asarray(cols, dtype=np.int64)
-        # witness columns build over the STABLE full review list when
-        # the caller maps pair rows through `cand` (identity
-        # spot-checked), so steady-state sweeps reuse them; otherwise
-        # over this call's pair_reviews
+        # witness columns build over a STABLE full review list, so
+        # steady-state sweeps reuse them: the one a CandidateRows view
+        # reads, or the inventory's when the caller maps a list's rows
+        # through `cand` (identity spot-checked); otherwise over this
+        # call's pair_reviews
         base = pair_reviews
         base_rows = rows_np
-        if cand is not None:
+        if isinstance(pair_reviews, CandidateRows):
+            base = pair_reviews.reviews
+            base_rows = pair_reviews.cand[rows_np]
+        elif cand is not None:
             full = self._inventory_reviews(target)
             cand_arr = np.asarray(cand, dtype=np.int64)
             if len(cand_arr) == len(pair_reviews) and (
@@ -2530,7 +2536,7 @@ class TpuDriver(RegoDriver):
         cand = np.flatnonzero(mask.any(axis=1))
         if cand.size == 0:
             return []
-        cand_reviews = [reviews[int(i)] for i in cand]
+        cand_reviews = CandidateRows(reviews, cand)
         # key pins the exact candidate set; constraint churn that does not
         # change membership keeps the (expensive) extraction cached
         feat_key = (self._data_gen, hash(cand.tobytes()))
@@ -2550,8 +2556,8 @@ class TpuDriver(RegoDriver):
             self._quarantine_clear(kind)
         keep = mask[cand[rows], cols]
         out = []
-        for ri, ci in zip(rows[keep], cols[keep]):
-            review = cand_reviews[int(ri)]
+        rows, cols = rows[keep], cols[keep]
+        for review, ci in zip(take_rows(cand_reviews, rows), cols):
             constraint = cons[int(ci)]
             spec = constraint.get("spec")
             spec = spec if isinstance(spec, dict) else {}
@@ -2691,6 +2697,8 @@ class TpuDriver(RegoDriver):
                 feats = self._adopt_restored_rows(kind, cand, feat_key,
                                                   fcache)
         if feats is None:
+            if isinstance(reviews, CandidateRows):
+                reviews = reviews.tolist()
             feats, buckets, n_pad = extract_batch(ct.program, self.strtab,
                                                   reviews)
             if feat_key is not None:
