@@ -578,8 +578,10 @@ def mesh_spread(driver, chips: int) -> dict:
     devs = jax.devices()[:chips]
     rows = {d.id: 0 for d in devs}
     n_bufs = 0
-    # the driver's mesh placements: (host array ref, device buffer)
-    for _ref, buf in list(driver._dev_mesh_cache.values()):
+    # the driver's mesh placements: (host array ref, device buffer,
+    # the audit that last used it)
+    for ent in list(driver._dev_mesh_cache.values()):
+        buf = ent[1]
         spec = tuple(buf.sharding.spec)
         if not spec or spec[0] != "data":
             continue  # replicated parameters and tables

@@ -712,6 +712,7 @@ MATERIALIZE_PATHS = ("vectorized", "exact", "capped")
 CANDIDATE_ROW_USES = ("resolved", "listed")
 JOIN_RENDER_SCOPES = ("holders", "inventory")
 SWEEP_LAYOUTS = ("rows", "constraints")
+KIND_SWEEP_PATHS = ("mesh", "single")
 CACHE_OUTCOMES = ("hit", "miss")
 STREAM_FLUSH_OUTCOMES = ("ok", "error", "skipped")
 PREVIEW_OUTCOMES = ("ok", "error", "invalid")
@@ -1211,6 +1212,18 @@ def report_sweep_layout(layout: str) -> None:
     REGISTRY.counter_add("gatekeeper_tpu_sweep_layout_total",
                          "Clause programs traced for the device, by the "
                          "tensor layout their shapes chose", layout=layout)
+
+
+def report_kind_sweep(path: str) -> None:
+    """One kind's device sweep dispatched by an audit, by where it ran:
+    "mesh" (split over the mesh's data axis) or "single" (one device,
+    joins included). An audit's `last_audit_path` says mesh when any
+    kind took the mesh; a kind that dropped to one device shows here."""
+    if path not in KIND_SWEEP_PATHS:
+        path = LABEL_FOLD
+    REGISTRY.counter_add("gatekeeper_tpu_audit_kind_sweeps_total",
+                         "Audit kind sweeps dispatched to the device, by "
+                         "mesh or single device", path=path)
 
 
 def report_msg_template_cache(outcome: str) -> None:

@@ -69,6 +69,9 @@ STAGES: dict[str, tuple[str, str]] = {
     "delta_serve": (
         "audit", "incremental encoded-row cache serve (dirty-row "
         "re-encode)"),
+    "place": (
+        "audit", "device placement of one kind's sweep inputs (sharded "
+        "over the mesh or on one device), cached by host array"),
     "dispatch": (
         "audit", "asynchronous launch of one kind's device sweep"),
     "device_sweep": (
@@ -77,6 +80,9 @@ STAGES: dict[str, tuple[str, str]] = {
     "materialize": (
         "audit", "violation message materialization from firing "
         "(row, constraint) pairs"),
+    "reassemble": (
+        "audit", "mesh sweep: per-shard result blocks sorted by first "
+        "row and merged into one kind's results"),
     "interp_eval": (
         "audit", "interpreter-path evaluation (kinds without device "
         "programs)"),

@@ -455,8 +455,10 @@ class TpuDriver(RegoDriver):
         self.warm_slots = warm_compile_slots()
         self._warm_sem = threading.Semaphore(self.warm_slots)
         # sharded/replicated device placements for the mesh sweep,
-        # keyed (id(leaf), data-leading?) with the _dev weakref pattern
+        # keyed (id(leaf), data-leading?) with the _dev weakref pattern;
+        # each entry notes the audit that last used it (_audit_seq)
         self._dev_mesh_cache: dict = {}
+        self._audit_seq = 0
         # which path the last audit's compiled kinds took, for
         # observability (bench.py reports it): "mesh(data=N)" | "single"
         # | "delta(k/n)" | "host" (no kind ran on the device)
@@ -1510,16 +1512,20 @@ class TpuDriver(RegoDriver):
     # LIVE host arrays (per-kind feature trees, padded vocab copies),
     # growing device-placement entries without bound — LRU-evict past
     # this many leaves (each eviction only drops a resident sharded
-    # buffer; the next sweep re-distributes that leaf)
+    # buffer; the next sweep re-distributes that leaf). The running
+    # audit's own leaves are never evicted: the general library's four
+    # mesh kinds place ~800 leaves, and a bound below an audit's leaves
+    # made every steady audit re-distribute all of them
     DEV_MESH_CACHE_MAX = 512
 
     def _dev_mesh(self, tree, data_leading: bool):
         """Mesh placement twin of _dev: leaves are device_put with a
         NamedSharding — leading axis split over "data" for feature
         tensors, fully replicated for params/tables — and cached weakly
-        by host-array identity (LRU-bounded by DEV_MESH_CACHE_MAX), so
-        steady-state mesh audits re-dispatch over resident sharded
-        buffers instead of re-distributing every sweep."""
+        by host-array identity (LRU-bounded by DEV_MESH_CACHE_MAX beyond
+        the running audit's leaves), so steady-state mesh audits
+        re-dispatch over resident sharded buffers instead of
+        re-distributing every sweep."""
         import weakref
 
         import jax
@@ -1527,15 +1533,16 @@ class TpuDriver(RegoDriver):
 
         mesh = self._mesh
         cache = self._dev_mesh_cache
+        audit = self._audit_seq if getattr(self, "_in_audit_sweep",
+                                           False) else None
 
         def put(arr):
             key = (id(arr), data_leading)
-            hit = cache.get(key)
+            hit = cache.pop(key, None)
             if hit is not None and hit[0]() is arr:
-                # LRU: refresh recency (dicts keep insertion order only,
-                # so a hit must re-insert to move to the back)
-                del cache[key]
-                cache[key] = hit
+                # LRU: a hit re-inserts to move to the back (dicts keep
+                # insertion order only)
+                cache[key] = (hit[0], hit[1], audit)
                 return hit[1]
             if data_leading and getattr(arr, "ndim", 0) >= 1:
                 spec = P("data", *([None] * (arr.ndim - 1)))
@@ -1546,9 +1553,12 @@ class TpuDriver(RegoDriver):
                 ref = weakref.ref(arr, lambda _r, k=key: cache.pop(k, None))
             except TypeError:
                 return d
-            cache[key] = (ref, d)
+            cache[key] = (ref, d, audit)
             while len(cache) > self.DEV_MESH_CACHE_MAX:
-                cache.pop(next(iter(cache)), None)
+                oldest = next(iter(cache))
+                if audit is not None and cache[oldest][2] == audit:
+                    break  # the rest is this audit's own
+                cache.pop(oldest, None)
             return d
 
         return jax.tree_util.tree_map(put, tree)
@@ -1556,8 +1566,11 @@ class TpuDriver(RegoDriver):
     # --------------------------------------------------------------- audit
 
     def _eval_audit(self, target: str, trace: Optional[list]) -> list[Result]:
-        self._in_audit_sweep = True  # arms the pre-materialization
-        try:                         # violations cap (when set)
+        # arms the pre-materialization violations cap (when set), and
+        # marks the mesh placements this audit uses (_dev_mesh)
+        self._in_audit_sweep = True
+        self._audit_seq += 1
+        try:
             return self._eval_audit_inner(target, trace)
         finally:
             self._in_audit_sweep = False
@@ -1933,11 +1946,11 @@ class TpuDriver(RegoDriver):
                 return None
             cand_reviews = CandidateRows(reviews, cand)
         use_mesh = self._mesh_shardable(len(cand_reviews))
+        feat_key = (self._data_gen, hash(cand.tobytes()))
         with timers.phase("encode", kind=kind):
-            trees = self._prepare_eval(
-                ct, kind, cand_reviews, cons,
-                (self._data_gen, hash(cand.tobytes())), cand=cand,
-                target=target, mesh=use_mesh)
+            trees = self._host_trees(ct, kind, cand_reviews, cons,
+                                     feat_key, cand=cand, target=target)
+        trees = self._place(kind, trees, feat_key, use_mesh)
         return mask, cand, cand_reviews, use_mesh, trees
 
     def _audit_dispatch(self, target, kind, ct, cons, reviews, lookup_ns,
@@ -2013,10 +2026,13 @@ class TpuDriver(RegoDriver):
                 self.aot.record_sig(ct.fingerprint, sig)
             import time as _time
 
+            from ..control.metrics import report_kind_sweep
+
             if use_mesh:
                 self._audit_used_mesh = True
             self._audit_on_device = True
             self.note_eval(kind, "device")
+            report_kind_sweep("mesh" if use_mesh else "single")
             return ("h", mask, cand, cand_reviews, handle, c_dev,
                     _time.monotonic())
         except DriverError:
@@ -2230,9 +2246,10 @@ class TpuDriver(RegoDriver):
                         from ..control.metrics import report_audit_shard
                         report_audit_shard("materialize", shard, dt)
             if blocks:
-                blocks.sort(key=lambda b: b[0])
-                for _r0, res in blocks:
-                    out.extend(res)
+                with timers.phase("reassemble", kind=kind):
+                    blocks.sort(key=lambda b: b[0])
+                    for _r0, res in blocks:
+                        out.extend(res)
         except DriverError:
             raise
         except Exception as e:
@@ -2297,8 +2314,11 @@ class TpuDriver(RegoDriver):
         if self._quarantine:
             self._quarantine_clear(kind)
         if on_device:  # else no review had a join key: nothing ran
+            from ..control.metrics import report_kind_sweep
+
             self._audit_on_device = True
             self.note_eval(kind, "join")
+            report_kind_sweep("single")
         with timers.phase("join_materialize", kind=kind):
             hit = np.flatnonzero(fires)
             if hit.size == 0:
@@ -2654,6 +2674,17 @@ class TpuDriver(RegoDriver):
     def _prepare_eval(self, ct: CompiledTemplate, kind: str,
                       reviews: list[dict], cons: list[dict], feat_key,
                       cand=None, target=None, mesh: bool = False):
+        """A sweep's (feats, enc, table, derived), placed on the device
+        (_host_trees, then _place)."""
+        trees = self._host_trees(ct, kind, reviews, cons, feat_key,
+                                 cand=cand, target=target)
+        return self._place(kind, trees, feat_key, mesh)
+
+    def _host_trees(self, ct: CompiledTemplate, kind: str,
+                    reviews: list[dict], cons: list[dict], feat_key,
+                    cand=None, target=None) -> tuple:
+        """A sweep's host arrays: features (cached by feat_key),
+        parameters, match table and derived columns."""
         params_key = (self._constraint_gen,
                       tuple((c.get("metadata") or {}).get("name", "")
                             for c in cons))
@@ -2711,20 +2742,31 @@ class TpuDriver(RegoDriver):
                 }
         derived = self._derived_arrays(kind, ct)
         table = self.match_tables.materialize_packed()
-        if mesh:
-            # SPMD sweep: features split over the data axis, everything
-            # else replicated across the mesh, all kept resident
+        return feats, enc, table, derived
+
+    def _place(self, kind: str, trees: tuple, feat_key, mesh: bool):
+        """Host trees on the device, each leaf cached by host array: a
+        steady audit finds every leaf placed already, so its "place"
+        phase stays near zero unless a placement cache misses."""
+        feats, enc, table, derived = trees
+        with profiling.timers().phase("place", kind=kind):
+            if mesh:
+                # SPMD sweep: features split over the data axis,
+                # everything else replicated across the mesh, all kept
+                # resident
+                if feat_key is not None:
+                    feats = self._dev_mesh(feats, data_leading=True)
+                return (feats, self._dev_mesh(enc, False),
+                        self._dev_mesh(table, False),
+                        self._dev_mesh(derived, False))
             if feat_key is not None:
-                feats = self._dev_mesh(feats, data_leading=True)
-            return (feats, self._dev_mesh(enc, False),
-                    self._dev_mesh(table, False),
-                    self._dev_mesh(derived, False))
-        if feat_key is not None:
-            # steady-state audit: keep the cached tensors device-resident.
-            # One-shot feats (webhook micro-batches) stay host-side — the
-            # identity cache would grow one dead entry per request.
-            feats = self._dev(feats)
-        return feats, self._dev(enc), self._dev(table), self._dev(derived)
+                # steady-state audit: keep the cached tensors device-
+                # resident. One-shot feats (webhook micro-batches) stay
+                # host-side — the identity cache would grow one dead
+                # entry per request.
+                feats = self._dev(feats)
+            return (feats, self._dev(enc), self._dev(table),
+                    self._dev(derived))
 
     def _patch_feats(self, ct: CompiledTemplate, meta: dict, cand,
                      target: str):
@@ -2832,7 +2874,7 @@ class TpuDriver(RegoDriver):
             d = fns[1](ment[1], rows, pos)
             if d.sharding != ment[1].sharding:
                 d = jax.device_put(d, ment[1].sharding)
-            self._dev_mesh_cache[(id(arr), True)] = (ment[0], d)
+            self._dev_mesh_cache[(id(arr), True)] = (ment[0], d, ment[2])
 
     def _derived_arrays(self, kind: str, ct: CompiledTemplate) -> dict:
         """Program-local derived columns, extended to the current vocab.
